@@ -16,6 +16,8 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "fig1_fused_ratio_census",
     "fig4_ratio_vs_tilesize",
@@ -107,6 +109,7 @@ def main(argv=None) -> None:
                     help="compare rows against benchmarks/thresholds.json; "
                          "exit 1 on a regression")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
     only = set(args.modules) or None

@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs.gcn import GCNConfig
 from repro.core.sparse.random import powerlaw_graph
 from repro.core.tilefusion import api
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_gcn_train_step
 from repro.models.gcn import GCN
 
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--lr", type=float, default=0.3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = GCNConfig(n_nodes=args.nodes, in_dim=args.hidden,
                     hidden_dim=args.hidden, out_dim=32, n_layers=2)

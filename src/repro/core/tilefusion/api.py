@@ -905,26 +905,39 @@ def _pallas_capable() -> bool:
     return compiled_or_forced()
 
 
-def _spmm_pallas_fits_vmem(entry: ScheduleEntry, c_col: int) -> bool:
-    """SpMM-SpMM kernel VMEM feasibility: the kernel stages all of C plus a
-    ``(t, n)`` one-hot per grid step, which scales with the *problem* size
-    (unlike the GeMM kernel, whose blocks scale only with t).  Auto
-    dispatch must fall back to the XLA executor above the budget instead
-    of handing Mosaic an unallocatable kernel."""
-    from ...kernels.ops import VMEM_BUDGET
+def pallas_vmem_bytes(entry: ScheduleEntry) -> dict:
+    """Scoped-VMEM working set of each Pallas kernel the entry's executor
+    runs — ``"wf0"`` (the op pair's fused-tile kernel) and, when wavefront
+    1 has rows, ``"wf1"`` (the ELL SpMM over the completed D1) — from the
+    kernels' own estimates.  Both the SpMM-SpMM wf0 kernel and the wf1
+    kernel stage a whole dense operand plus a one-hot that is ``n`` wide,
+    so their working sets grow with the problem, not with the tile."""
+    from ...kernels import spmm, tile_fused_gemm_spmm, tile_fused_spmm_spmm
     ds = entry.dsched
+    isz, c_col = entry.dtype_bytes, entry.c_col
     t, n = ds.t_pad, ds.n_i
-    j0 = ds.j_rows0.shape[1]
-    w0 = ds.ell_cols0.shape[2]
-    w1 = ds.width_cap if ds.width_cap is not None else n
-    elems = (n * c_col          # C staged in full
-             + t * n            # op-1 one-hot w1_mat
-             + 2 * t * c_col    # D1 tile + spill block
-             + 2 * t * w1       # op-1 ELL body
-             + 2 * j0 * w0      # fused-rows ELL
-             + j0 * t           # densified A tile
-             + j0 * c_col)      # fused rows out
-    return elems * entry.dtype_bytes <= VMEM_BUDGET
+    j0, w0 = ds.ell_cols0.shape[1:]
+    if entry.b_is_sparse:
+        # the op-1 body is at most cap wide (pad-to-max: at most n)
+        w1 = ds.width_cap if ds.width_cap is not None else n
+        out = {"wf0": tile_fused_spmm_spmm.vmem_bytes(
+            t, w1, j0, w0, n, c_col, isz)}
+    else:
+        out = {"wf0": tile_fused_gemm_spmm.vmem_bytes(
+            j0, w0, t, entry.b_col, c_col, isz)}
+    if ds.j_rows1.size:
+        out["wf1"] = spmm.vmem_bytes(spmm.BLOCK_ROWS, ds.ell_cols1.shape[2],
+                                     n, c_col, isz)
+    return out
+
+
+def pallas_fits_vmem(entry: ScheduleEntry) -> bool:
+    """True when every kernel of the entry's Pallas executor fits the
+    scoped-VMEM budget it is compiled under (``kernels.config
+    .VMEM_BUDGET``).  Above it ``select_backend`` resolves to the XLA
+    executor instead of handing Mosaic an unallocatable kernel."""
+    from ...kernels.config import VMEM_BUDGET
+    return max(pallas_vmem_bytes(entry).values()) <= VMEM_BUDGET
 
 
 def select_backend(entry: ScheduleEntry) -> str:
@@ -942,13 +955,11 @@ def select_backend(entry: ScheduleEntry) -> str:
         # off-model fixed costs) — Eq 3 says the intermediate round-trips
         # memory either way, so take the simpler code
         return "unfused"
-    if fused_ops._is_uniform(entry.dsched) and _pallas_capable():
-        # both op pairs lower to wavefront-0 Pallas kernels on a uniform
-        # grid (GeMM-SpMM and, via the hybrid op-1 gather, SpMM-SpMM)
-        if not entry.b_is_sparse:
-            return "pallas"
-        if _spmm_pallas_fits_vmem(entry, entry.c_col):
-            return "pallas"
+    if (fused_ops._is_uniform(entry.dsched) and _pallas_capable()
+            and pallas_fits_vmem(entry)):
+        # both op pairs lower to Pallas kernels on a uniform grid (GeMM-SpMM
+        # and, via the hybrid op-1 gather, SpMM-SpMM)
+        return "pallas"
     return "xla"
 
 

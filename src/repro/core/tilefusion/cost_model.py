@@ -45,10 +45,6 @@ def operand_dtype_bytes(*operands, default: int = 4) -> int:
                 continue
     return int(default)
 
-#: Default fast-memory budget: 64 MiB of the ~128 MiB v5e VMEM (leave half for
-#: double-buffering and the matmul operands), expressed in bytes.
-DEFAULT_VMEM_BUDGET_BYTES = 64 * 1024 * 1024
-
 #: CPU-style default used by benchmarks mirroring the paper's setting
 #: (L1+L2+L3/core on CascadeLake ~ 2.4 MB).
 DEFAULT_CPU_CACHE_BYTES = int(2.4 * 1024 * 1024)

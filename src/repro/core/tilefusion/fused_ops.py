@@ -22,16 +22,24 @@ from .schedule import DeviceSchedule
 def _ell_rows(cols, vals, table):
     """rows[j] = Σ_w vals[j, w] · table[cols[j, w]] — scanned over w so the
     gather never materializes the (…, w, c_col) tensor (VMEM/cache friendly,
-    mirrors the kernel's one-hot accumulation loop)."""
-    w = cols.shape[-1]
+    mirrors the kernel's one-hot accumulation loop).
+
+    Slot 0 seeds the carry instead of a zeros array: the carry then has
+    the body's exact type, including the manual mesh axes the operands
+    vary over under ``shard_map(check_vma=True)``, which a fresh zeros
+    array would not carry."""
+    def term(cw, vw):
+        return vw[..., None] * table[cw]
+
+    if cols.shape[-1] == 0:
+        return jnp.zeros(cols.shape[:-1] + (table.shape[-1],), table.dtype)
 
     def body(acc, wv):
-        cw, vw = wv                                     # (..., ) per slot
-        return acc + vw[..., None] * table[cw], None
+        return acc + term(*wv), None
 
-    init = jnp.zeros(cols.shape[:-1] + (table.shape[-1],), table.dtype)
-    acc, _ = jax.lax.scan(body, init,
-                          (jnp.moveaxis(cols, -1, 0), jnp.moveaxis(vals, -1, 0)))
+    acc, _ = jax.lax.scan(body, term(cols[..., 0], vals[..., 0]),
+                          (jnp.moveaxis(cols[..., 1:], -1, 0),
+                           jnp.moveaxis(vals[..., 1:], -1, 0)))
     return acc
 
 

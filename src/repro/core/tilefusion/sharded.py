@@ -9,8 +9,7 @@ with contiguous tile groups balanced by their Eq-3 cost
 (``scheduler.balanced_contiguous_partition``) so every shard streams
 comparable fused-tile bytes.
 
-Execution model (per shard, under the ``models/sharding.py`` shard_map
-shim):
+Execution model (per shard, under ``jax.shard_map``):
 
   wavefront 0   each shard computes the D1 rows of its own tiles (GeMM or
                 hybrid-ELL op-1 SpMM) and its fused second-op rows — zero
@@ -576,7 +575,7 @@ def _shard_executor(shard: ShardedSchedule, mesh, kind: str):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ...models.sharding import mesh_row_repl_axes, shard_map
+    from ...models.sharding import mesh_row_repl_axes
 
     row_axes, repl_axes, depth_axes = mesh_row_repl_axes(mesh, shard.layout)
     mesh_sizes = dict(zip(mesh.axis_names, np.shape(mesh.devices)))
@@ -756,9 +755,9 @@ def _shard_executor(shard: ShardedSchedule, mesh, kind: str):
         flat_spec = P(tuple(depth_axes) or None,
                       tuple(repl_axes) or None)
         out_specs = (out_specs, flat_spec)
-    mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs,
-                       check_vma=not async_halo)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs,
+                           check_vma=not async_halo)
     fn = jax.jit(mapped)
     halo_bufs: dict = {}
 

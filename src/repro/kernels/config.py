@@ -7,12 +7,38 @@ here so a real backend never silently falls into interpret mode.
 
 ``PALLAS_INTERPRET=0/1`` force-overrides in either direction (used by the
 kernel tests to pin a mode regardless of backend).
+
+The scoped-VMEM budget lives here too: the tile-fusion kernels hand it to
+Mosaic as ``vmem_limit_bytes`` and dispatch checks each kernel's working
+set against it, so the limit a kernel compiles under and the limit auto
+dispatch admits it by can never drift apart.
 """
 from __future__ import annotations
 
 import os
 
 import jax
+
+#: Scoped-VMEM limit of the tile-fusion kernels: half of v5e's 128 MiB per
+#: TensorCore (Mosaic's default scoped limit is 16 MiB).
+VMEM_BUDGET = 64 * 1024 * 1024
+
+
+def compiler_params():
+    """Mosaic compiler parameters shared by the tile-fusion kernels."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET)
+
+
+def vmem_buffer_bytes(shape, itemsize: int) -> int:
+    """Bytes one VMEM buffer of ``shape`` occupies: the trailing two dims
+    pad to the (sublane, 128-lane) tile, 8 sublanes of 32-bit words."""
+    *lead, rows, lanes = (1, 1) + tuple(int(d) for d in shape)
+    sub = 8 * max(4 // int(itemsize), 1)
+    n = -(-rows // sub) * sub * (-(-lanes // 128) * 128)
+    for d in lead:
+        n *= d
+    return n * int(itemsize)
 
 
 def default_interpret() -> bool:

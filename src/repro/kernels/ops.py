@@ -5,40 +5,33 @@ CPU backend they run in interpret mode (kernel body executed as XLA ops) —
 same numerics, same blocking.  The choice is made once, in
 ``config.default_interpret`` (``PALLAS_INTERPRET`` can force either).
 Each op also exposes an ``impl="xla"`` escape hatch used by the dry-run
-(representative HLO without a TPU custom-call) and by sizes whose working set
-exceeds the VMEM budget.
+(representative HLO without a TPU custom-call).  ``VMEM_BUDGET``
+(``config``) is the scoped-VMEM limit the tile-fusion kernels compile under.
 """
 from __future__ import annotations
 
 from . import ref
+from .config import VMEM_BUDGET
 from .config import default_interpret as _interpret
 from .fused_ffn import fused_ffn as _fused_ffn_pallas
 from .flash_attention import flash_attention as _flash_pallas
 from .moe import fused_moe_ffn as _moe_pallas
+from .spmm import BLOCK_ROWS
 from .spmm import spmm_ell as _spmm_pallas
 from .tile_fused_gemm_spmm import tile_fused_gemm_spmm_wf0 as _tf_pallas
+from .tile_fused_gemm_spmm import vmem_bytes as _tf_vmem_bytes
 from .tile_fused_spmm_spmm import tile_fused_spmm_spmm_wf0 as _tfss_pallas
-
-#: VMEM budget used by choose_kernel_tile (bytes); ~half of v5e VMEM.
-VMEM_BUDGET = 64 * 1024 * 1024
 
 
 def choose_kernel_tile(b_col: int, c_col: int, j0_max: int, w: int,
                        dtype_bytes: int = 4,
                        budget: int = VMEM_BUDGET) -> int:
     """TPU form of the paper's step-2 splitting: the largest 128-aligned
-    uniform tile size t whose VMEM working set fits the budget.
-
-    Working set (elements): B_t (t*bCol) + C (bCol*cCol) + D1_t (t*cCol)
-      + ELL (2*j0_max*w) + densified A tile (j0_max*t) + rows (j0_max*cCol).
-    """
-    t = 128
-    best = 128
-    while t <= 8192:
-        elems = (t * b_col + b_col * c_col + t * c_col
-                 + 2 * j0_max * w + j0_max * t + j0_max * c_col)
-        if elems * dtype_bytes > budget:
-            break
+    uniform tile size t whose VMEM working set
+    (``tile_fused_gemm_spmm.vmem_bytes``) fits the budget."""
+    t = best = 128
+    while t <= 8192 and _tf_vmem_bytes(j0_max, w, t, b_col, c_col,
+                                       dtype_bytes) <= budget:
         best = t
         t *= 2
     return best
@@ -60,7 +53,8 @@ def tile_fused_spmm_spmm_wf0(op1_cols, op1_vals, d1_spill, cols0, vals0, c,
                         interpret=_interpret())
 
 
-def spmm_ell(cols, vals, x, *, block_rows: int = 256, impl: str = "pallas"):
+def spmm_ell(cols, vals, x, *, block_rows: int = BLOCK_ROWS,
+             impl: str = "pallas"):
     if impl == "xla":
         return ref.spmm_ell(cols, vals, x)
     return _spmm_pallas(cols, vals, x, block_rows=block_rows,

@@ -17,7 +17,7 @@ Per tile ``v`` covering rows ``[v*t, (v+1)*t)``:
 The tile size ``t`` is the TPU analogue of the paper's step-2 splitting: VMEM
 working set is ``t*(bCol+cCol) + j0_max*(t+cCol)`` elements, uniform across
 tiles, so step 2 reduces to choosing the largest 128-aligned ``t`` under the
-VMEM budget (see ``ops.choose_kernel_tile``).
+VMEM budget (``vmem_bytes`` below; see ``ops.choose_kernel_tile``).
 
 Wavefront 1 (the post-barrier tiles) runs as a second kernel (``spmm.py``)
 reading the now-complete ``D1`` — the ``pallas_call`` boundary *is* the
@@ -31,7 +31,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .config import resolve_interpret
+from .config import compiler_params, resolve_interpret, vmem_buffer_bytes
+
+
+def densify_ell(cols, vals, n_cols: int):
+    """Dense ``(rows, n_cols)`` matrix of an ELL block by one-hot
+    accumulation, one slot at a time.  The slot loop is unrolled at trace
+    time (the width is static and small) so every slot read is a static
+    lane slice, which Mosaic lowers; a loop-counter index would be a
+    dynamic lane slice, which it does not."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1)
+    acc = jnp.zeros((cols.shape[0], n_cols), vals.dtype)
+    for w in range(cols.shape[1]):
+        onehot = (cols[:, w:w + 1] == iota).astype(vals.dtype)
+        acc = acc + vals[:, w:w + 1] * onehot
+    return acc
 
 
 def _kernel(cols_ref, vals_ref, b_ref, c_ref, d1_ref, rows_ref):
@@ -41,20 +55,26 @@ def _kernel(cols_ref, vals_ref, b_ref, c_ref, d1_ref, rows_ref):
     d1_ref[...] = d1_t.astype(d1_ref.dtype)
 
     # ---- fused SpMM part: densify tile-local A rows, multiply on MXU ----
-    cols = cols_ref[0]                                          # (j0_max, w)
-    vals = vals_ref[0]                                          # (j0_max, w)
-    t = d1_t.shape[0]
-    iota_t = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)     # (1, t)
-
-    def body(w, acc):
-        onehot = (cols[:, w][:, None] == iota_t).astype(vals.dtype)  # (j0_max, t)
-        return acc + vals[:, w][:, None] * onehot
-
-    w_mat = jax.lax.fori_loop(
-        0, cols.shape[1], body,
-        jnp.zeros((cols.shape[0], t), vals.dtype))              # dense A tile
+    w_mat = densify_ell(cols_ref[0], vals_ref[0], d1_t.shape[0])  # (j0, t)
     rows = jnp.dot(w_mat, d1_t, preferred_element_type=jnp.float32)
     rows_ref[0] = rows.astype(rows_ref.dtype)
+
+
+def vmem_bytes(j0_max: int, w: int, t: int, b_col: int, c_col: int,
+               itemsize: int) -> int:
+    """Scoped-VMEM working set of one grid step: every blocked operand
+    double-buffered by the pipeline, plus the f32 D1 tile, the densified
+    A tile with its one-hot, and the f32 fused rows."""
+    blocks = (vmem_buffer_bytes((j0_max, w), 4)
+              + vmem_buffer_bytes((j0_max, w), itemsize)
+              + vmem_buffer_bytes((t, b_col), itemsize)
+              + vmem_buffer_bytes((b_col, c_col), itemsize)
+              + vmem_buffer_bytes((t, c_col), itemsize)
+              + vmem_buffer_bytes((j0_max, c_col), itemsize))
+    temps = (vmem_buffer_bytes((t, c_col), 4)
+             + 2 * vmem_buffer_bytes((j0_max, t), itemsize)
+             + vmem_buffer_bytes((j0_max, c_col), 4))
+    return 2 * blocks + temps
 
 
 def tile_fused_gemm_spmm_wf0(cols0: jax.Array, vals0: jax.Array,
@@ -100,5 +120,6 @@ def _tile_fused_gemm_spmm_wf0(cols0, vals0, b, c, *, t: int, interpret: bool):
             pl.BlockSpec((1, j0_max, c_col), lambda v: (v, 0, 0)),
         ],
         out_shape=out_shape,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(cols0, vals0, b, c)

@@ -32,43 +32,44 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .config import resolve_interpret
+from .config import compiler_params, resolve_interpret, vmem_buffer_bytes
+from .tile_fused_gemm_spmm import densify_ell
 
 
 def _kernel(op1_cols_ref, op1_vals_ref, spill_ref, cols_ref, vals_ref,
             c_ref, d1_ref, rows_ref, *, n_c_rows: int):
     # ---- op-1 SpMM part: densify the tile's op-1 ELL body, gather C ----
-    o_cols = op1_cols_ref[0]                                    # (t, w1)
-    o_vals = op1_vals_ref[0]                                    # (t, w1)
-    c = c_ref[...]                                              # (n, cCol)
-    iota_n = jax.lax.broadcasted_iota(jnp.int32, (1, n_c_rows), 1)
-
-    def op1_body(w, acc):
-        onehot = (o_cols[:, w][:, None] == iota_n).astype(o_vals.dtype)
-        return acc + o_vals[:, w][:, None] * onehot
-
-    w1_mat = jax.lax.fori_loop(
-        0, o_cols.shape[1], op1_body,
-        jnp.zeros((o_cols.shape[0], n_c_rows), o_vals.dtype))   # (t, n)
-    d1_t = jnp.dot(w1_mat, c, preferred_element_type=jnp.float32)
+    w1_mat = densify_ell(op1_cols_ref[0], op1_vals_ref[0], n_c_rows)  # (t, n)
+    d1_t = jnp.dot(w1_mat, c_ref[...], preferred_element_type=jnp.float32)
     d1_t = d1_t + spill_ref[...]             # hub-row tails past the cap
     d1_ref[...] = d1_t.astype(d1_ref.dtype)
 
     # ---- fused SpMM part: tile-local A rows, multiply on MXU ----
-    cols = cols_ref[0]                                          # (j0_max, w0)
-    vals = vals_ref[0]
-    t = d1_t.shape[0]
-    iota_t = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
-
-    def fused_body(w, acc):
-        onehot = (cols[:, w][:, None] == iota_t).astype(vals.dtype)
-        return acc + vals[:, w][:, None] * onehot
-
-    w0_mat = jax.lax.fori_loop(
-        0, cols.shape[1], fused_body,
-        jnp.zeros((cols.shape[0], t), vals.dtype))              # (j0_max, t)
+    w0_mat = densify_ell(cols_ref[0], vals_ref[0], d1_t.shape[0])  # (j0, t)
     rows = jnp.dot(w0_mat, d1_t, preferred_element_type=jnp.float32)
     rows_ref[0] = rows.astype(rows_ref.dtype)
+
+
+def vmem_bytes(t: int, w1: int, j0_max: int, w0: int, n: int, c_col: int,
+               itemsize: int) -> int:
+    """Scoped-VMEM working set of one grid step: every blocked operand
+    (all of ``C`` included) double-buffered by the pipeline, plus the
+    ``(t, n)`` densified op-1 rows and the ``(j0_max, t)`` densified A
+    tile (each with its one-hot), the f32 D1 tile and fused rows.  Grows
+    with ``n``, not with nnz."""
+    blocks = (vmem_buffer_bytes((t, w1), 4)
+              + vmem_buffer_bytes((t, w1), itemsize)
+              + vmem_buffer_bytes((t, c_col), itemsize)
+              + vmem_buffer_bytes((j0_max, w0), 4)
+              + vmem_buffer_bytes((j0_max, w0), itemsize)
+              + vmem_buffer_bytes((n, c_col), itemsize)
+              + vmem_buffer_bytes((t, c_col), itemsize)
+              + vmem_buffer_bytes((j0_max, c_col), itemsize))
+    temps = (2 * vmem_buffer_bytes((t, n), itemsize)
+             + 2 * vmem_buffer_bytes((j0_max, t), itemsize)
+             + vmem_buffer_bytes((t, c_col), 4)
+             + vmem_buffer_bytes((j0_max, c_col), 4))
+    return 2 * blocks + temps
 
 
 def tile_fused_spmm_spmm_wf0(op1_cols: jax.Array, op1_vals: jax.Array,
@@ -126,5 +127,6 @@ def _tile_fused_spmm_spmm_wf0(op1_cols, op1_vals, d1_spill, cols0, vals0, c,
             pl.BlockSpec((1, j0_max, c_col), lambda v: (v, 0, 0)),
         ],
         out_shape=out_shape,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(op1_cols, op1_vals, d1_spill, cols0, vals0, c)

@@ -1,9 +1,15 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(
+    [os.environ.get("XLA_FLAGS", ""),
+     "--xla_force_host_platform_device_count=512"]).strip()
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST stay first: jax locks the device count on first
-init, and the production meshes need 512 placeholder host devices.
+The lines above MUST stay first: jax locks the platform and the device
+count on first init.  The dry-run compiles on the host CPU alone (pinned,
+never an attached accelerator), and the production meshes need 512
+placeholder host devices; the flag is appended so flags the caller set
+survive.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2.5-3b --shape train_4k

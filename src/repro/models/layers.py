@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharding import ShardingRules, shard, shard_map
+from .sharding import ShardingRules, shard
 
 
 def _init(key, shape, scale=None, dtype=jnp.float32):
@@ -394,7 +394,7 @@ def moe_apply(p, cfg, x, rules: Optional[ShardingRules],
     ba, mx = rules.batch_axes, rules.model_axis
     shared_spec = None if shared is None else {
         "w_gate": P(None, mx), "w_up": P(None, mx), "w_down": P(mx, None)}
-    f = shard_map(
+    f = jax.shard_map(
         lambda router, w1, w3, w2, sh, xl: jax.lax.psum(
             local_moe(router, w1, w3, w2, sh, xl), mx),
         mesh=rules.mesh,

@@ -15,38 +15,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Compat shim: ``jax.shard_map`` graduated from
-    ``jax.experimental.shard_map`` (and renamed ``check_rep`` →
-    ``check_vma``) only in newer JAX; resolve whichever this install has.
-    All shard_map'd layers (and the sharded tile-fusion executors) go
-    through here.
-
-    The replication-check keyword is threaded by *inspecting the resolved
-    function's signature*, not by assuming which spelling goes with which
-    import path: mid-migration JAX releases shipped the top-level
-    ``jax.shard_map`` still taking ``check_rep``, and the experimental
-    module later grew ``check_vma`` — pinning the keyword to the import
-    path silently dropped the caller's flag on those versions.
-    """
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    kwargs = {}
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):          # builtins without signatures
-        params = {}
-    if "check_vma" in params:
-        kwargs["check_vma"] = check_vma
-    elif "check_rep" in params:
-        kwargs["check_rep"] = check_vma
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **kwargs)
-
-
 def mesh_row_repl_axes(mesh, layout: str = "1d") -> tuple:
     """Split a mesh's axis names into (row_axes, repl_axes, depth_axes)
     for the sharded tile-fusion executors.

@@ -1,4 +1,4 @@
-"""Sharded tile-fusion dispatch: partition, halo, cache keying, shim.
+"""Sharded tile-fusion dispatch: partition, halo, cache keying.
 
 Host-side structure tests (the partitioner and ``ShardedSchedule`` builder
 are pure numpy) run everywhere; execution parity over a *real* multi-device
@@ -21,7 +21,6 @@ from repro.core.sparse.random import banded_spd, hub_powerlaw, powerlaw_graph
 from repro.core.tilefusion import api, fused_ref, sharded
 from repro.core.tilefusion.cost_model import shard_comm_model
 from repro.core.tilefusion.scheduler import balanced_contiguous_partition
-from repro.models.sharding import shard_map
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KNOBS = dict(p=2, cache_size=30_000.0, ct_size=32)
@@ -313,19 +312,22 @@ def test_auto_with_mesh_dispatches_sharded_even_unfusable():
                                rtol=2e-3, atol=2e-3)
 
 
-def test_shard_map_shim_threads_check_kwarg():
-    """The shim must accept ``check_vma`` against whichever spelling the
-    installed JAX uses, on a real mesh, in both True/False modes."""
-    mesh = _mesh(1)
-
-    def f(x):
-        return jax.lax.psum(x.sum(keepdims=True), "shards")
-
-    x = jnp.arange(4, dtype=jnp.float32)
-    for check in (True, False):
-        g = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                      check_vma=check)
-        assert float(jax.jit(g)(x)[0]) == 6.0
+def test_ell_rows_carry_matches_body_under_check_vma():
+    """``_ell_rows`` scans a carry over the ELL slots; under
+    ``shard_map(check_vma=True)`` its operands vary over the mesh axes, so
+    the carry must too — a plain zeros init fails the scan's type check
+    (the layout every sync sharded executor runs)."""
+    from repro.core.tilefusion import fused_ops
+    rng = np.random.default_rng(5)
+    cols = rng.integers(0, 6, (4, 3)).astype(np.int32)
+    vals = rng.standard_normal((4, 3)).astype(np.float32)
+    table = rng.standard_normal((6, 2)).astype(np.float32)
+    g = jax.shard_map(lambda c, v, t: fused_ops._ell_rows(c, v, t[0]),
+                      mesh=_mesh(1), in_specs=(P("shards"),) * 3,
+                      out_specs=P("shards"), check_vma=True)
+    got = jax.jit(g)(cols, vals, table[None])
+    want = np.einsum("jw,jwc->jc", vals, table[cols])
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
 
 
 # --------------------------------------------------------------------------
@@ -338,17 +340,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 assert len(jax.devices()) == 8, jax.devices()
 mesh = Mesh(np.array(jax.devices()), ("shards",))
 
-# 1) the shard_map shim on a real 8-way mesh, both check modes
-from repro.models.sharding import shard_map
-def f(x):
-    return jax.lax.psum(x.sum(keepdims=True), "shards")
-for check in (True, False):
-    g = shard_map(f, mesh=mesh, in_specs=(P("shards"),), out_specs=P(),
-                  check_vma=check)
-    out = jax.jit(g)(jnp.arange(16, dtype=jnp.float32))
-    assert float(out[0]) == 120.0, out
-
-# 2) sharded tile-fusion parity on the 8-way mesh, both op pairs
+# 1) sharded tile-fusion parity on the 8-way mesh, both op pairs
 from repro.core.sparse.random import hub_powerlaw
 from repro.core.tilefusion import api, fused_ref
 a = hub_powerlaw(96, 4, seed=0)
@@ -370,7 +362,7 @@ np.testing.assert_allclose(np.asarray(got),
 entry = api.get_schedule(a, b_col=8, c_col=8, mesh=mesh, **knobs)
 assert entry.shard.n_shards == 8
 
-# 3) 2-D mesh cells: both layouts x both combines on a real 4x2 partition
+# 2) 2-D mesh cells: both layouts x both combines on a real 4x2 partition
 mesh2d = Mesh(np.array(jax.devices()).reshape(4, 2), ("x", "y"))
 want_g = fused_ref.unfused_gemm_spmm(a, b, cg)
 outs = []
@@ -394,7 +386,7 @@ assert e15.shard.layout == "1.5d"
 stats = api.schedule_cache_stats()
 assert stats["layout_15d"] >= 1 and stats["layout_1d"] >= 1, stats
 
-# 4) 2.5D cell: a real 2x2x2 cube, depth-2 staged halo exchange, sync and
+# 3) 2.5D cell: a real 2x2x2 cube, depth-2 staged halo exchange, sync and
 # async overlap both matching the oracle and each other exactly
 import dataclasses
 mesh3d = Mesh(np.array(jax.devices()).reshape(2, 2, 2), ("x", "y", "z"))
@@ -429,14 +421,40 @@ print("FORCED8 OK")
 """
 
 
-def test_forced_8_device_host_mesh():
+def _run_forced_host(script: str, n_devices: int, *args: str):
     env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}",
                JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(REPO_ROOT, "src"),
                     os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))
-    out = subprocess.run([sys.executable, "-c", _FORCED_SCRIPT], env=env,
+    out = subprocess.run([sys.executable, "-c", script, *args], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-4000:]
-    assert "FORCED8 OK" in out.stdout
+    return out.stdout
+
+
+def test_forced_8_device_host_mesh():
+    assert "FORCED8 OK" in _run_forced_host(_FORCED_SCRIPT, 8)
+
+
+# the chip smoke's four-chip phase, on a forced 4-device host: both op
+# pairs on a 2x2 mesh, 1d and 1.5d, overlap off (check_vma=True) and on
+_FORCED4_SCRIPT = r"""
+import importlib.util, os, sys
+import jax
+assert len(jax.devices()) == 4, jax.devices()
+spec = importlib.util.spec_from_file_location(
+    "chip_smoke", os.path.join(sys.argv[1], "chip_smoke.py"))
+smoke = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(smoke)
+a = smoke.powerlaw_graph(2048, smoke.ARXIV_AVG_DEGREE, seed=0)
+smoke.phase_sharded(jax.devices(), a, width=16, seed=0)
+print("FORCED4 OK")
+"""
+
+
+def test_forced_4_device_2x2_mesh_smoke_phase():
+    out = _run_forced_host(_FORCED4_SCRIPT, 4, REPO_ROOT)
+    assert "FORCED4 OK" in out
+    assert out.count("vs oracle and single-device") == 8, out
