@@ -1,0 +1,11 @@
+"""Self-tests of the benchmark: ``python -m pytest bench/``.  They run on
+the CPU at small sizes; nothing here needs a chip."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (os.path.join(_ROOT, "src"), _ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
