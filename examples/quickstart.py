@@ -47,8 +47,9 @@ d = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
 err = float(np.abs(np.asarray(d) - d_ref).max() / np.abs(d_ref).max())
 print(f"fused (backend=auto -> {api.select_backend(entry)}) "
       f"vs oracle rel err: {err:.2e}")
-print(f"inspector: {entry.inspector_s*1e3:.1f}ms once, then cached — "
-      f"stats {api.schedule_cache_stats()}")
+stats = api.schedule_cache_stats()
+print(f"inspector: {stats['inspect_s']*1e3:.1f}ms once, then cached — "
+      f"stats {stats}")
 
 # ---- 3. GCN training on the fused path ----
 cfg = gcn_cfg.REDUCED

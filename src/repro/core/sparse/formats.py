@@ -133,16 +133,19 @@ def csr_content_digest(a: CSR) -> bytes:
     schedule op-1 pack memo."""
     digest = getattr(a, "_content_digest", None)
     if digest is None:
-        h = hashlib.blake2b(digest_size=16)
-        h.update(np.asarray([a.n_rows, a.n_cols], np.int64).tobytes())
-        h.update(np.ascontiguousarray(a.indptr, np.int32).tobytes())
-        h.update(np.ascontiguousarray(a.indices, np.int32).tobytes())
-        # tag the source dtype: the value bytes below are canonicalized to
-        # f64, so without this, identical patterns held at f32 vs bf16
-        # would collide — and dtype_bytes-priced entries would alias
-        h.update(str(a.data.dtype).encode())
-        h.update(np.ascontiguousarray(a.data, np.float64).tobytes())
-        digest = h.digest()
+        from ...trace import span   # jax-backed: imported on first use
+        with span("digest"):
+            h = hashlib.blake2b(digest_size=16)
+            h.update(np.asarray([a.n_rows, a.n_cols], np.int64).tobytes())
+            h.update(np.ascontiguousarray(a.indptr, np.int32).tobytes())
+            h.update(np.ascontiguousarray(a.indices, np.int32).tobytes())
+            # tag the source dtype: the value bytes below are canonicalized
+            # to f64, so without this, identical patterns held at f32 vs
+            # bf16 would collide — and dtype_bytes-priced entries would
+            # alias
+            h.update(str(a.data.dtype).encode())
+            h.update(np.ascontiguousarray(a.data, np.float64).tobytes())
+            digest = h.digest()
         object.__setattr__(a, "_content_digest", digest)
     return digest
 

@@ -46,7 +46,11 @@ are LRU-bounded at ``REPRO_SCHEDULE_CACHE_ENTRIES`` entries each (env var,
 default 128); streaming workloads that touch unbounded pattern sets evict
 oldest-first instead of growing without bound.
 ``schedule_cache_stats()`` reports hits/misses/evictions plus live entry
-counts of both caches.
+counts of both caches, and the host seconds spent inspecting
+(``inspect_s``) and packing ELLs (``pack_s``, with ``ell_hits`` /
+``ell_misses``).  The same work shows in a profile as the host spans
+``repro.get_schedule``, ``repro.inspect``, ``repro.pack``,
+``repro.digest`` and ``repro.dispatch`` (``repro.trace``).
 
 **One knob object (``spec=``).**  Every dispatch knob below lives on a
 frozen ``FusionSpec`` (``spec.py``) and callers pass ``spec=``; the spec's
@@ -104,6 +108,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...trace import scope, span
 from ..sparse.formats import (CSR, DEFAULT_WIDTH_QUANTILE,
                               csr_content_digest, hybrid_width_cap)
 from . import cost_model, fused_ops, reorder, sharded
@@ -288,11 +293,9 @@ class ScheduleEntry:
     b_col: int
     c_col: int
     b_is_sparse: bool
-    inspector_s: float          # wall time of the one build (not per call)
     #: Eq-3-derived fast-memory traffic prediction, computed once at build
     #: (select_backend reads it on every "auto" call)
     traffic_model: dict = dataclasses.field(default_factory=dict)
-    hits: int = 0               # cache hits since the build
     #: set on autotune winners: the (ct_size, cache_size, width_cap) the
     #: sweep picked
     autotuned: tuple | None = None
@@ -335,13 +338,29 @@ class ScheduleEntry:
 
 _schedule_cache: "collections.OrderedDict" = collections.OrderedDict()
 _ell_cache: "collections.OrderedDict" = collections.OrderedDict()
+#: ``inspect_s`` sums the host seconds of every inspection (cache misses
+#: and the serving tier's incremental patches), ``pack_s`` those of every
+#: ELL pack (``_csr_ell`` misses and the op-1 pack)
 _stats = {"hits": 0, "misses": 0, "evictions": 0, "ell_evictions": 0,
-          "autotune_sweeps": 0, "incremental_patches": 0}
+          "autotune_sweeps": 0, "incremental_patches": 0,
+          "inspect_s": 0.0, "pack_s": 0.0, "ell_hits": 0, "ell_misses": 0}
 _lock = threading.Lock()
 #: The ELL cache has its own lock so its atomic check-and-build (which can
 #: allocate a full-matrix padded ELL) never stalls schedule-cache hits.
 #: Lock order where both are held: _lock, then _ell_lock.
 _ell_lock = threading.Lock()
+
+
+def count_inspect(seconds: float) -> None:
+    """Add one inspection's host seconds to ``inspect_s``."""
+    with _lock:
+        _stats["inspect_s"] += seconds
+
+
+def count_pack(seconds: float) -> None:
+    """Add one ELL pack's host seconds to ``pack_s``."""
+    with _ell_lock:
+        _stats["pack_s"] += seconds
 
 
 def _cache_budget() -> int:
@@ -522,92 +541,94 @@ def get_schedule(a: CSR, *, b_col: int, c_col: int,
     rectangular patterns; a forced ordering raises on them.  The knob
     joins the cache key (``_spec_key``); it does not compose with
     ``bucket``."""
-    spec = _coerce_spec(spec, legacy, "get_schedule")
-    if spec.dtype_bytes is None:
-        spec = dataclasses.replace(spec, dtype_bytes=4)
-    else:
-        spec = dataclasses.replace(spec, dtype_bytes=int(spec.dtype_bytes))
-    transpose = spec.transpose
-    a_eff = a.transpose() if transpose else a
-    cap = _resolve_width_cap(a_eff, spec.width_cap)
-    mk = sharded.mesh_key(spec.mesh)
-    sk = _shard_knobs_key(mk, spec.shard_combine, spec.shard_layout)
-    bucket = spec.bucket
-    if bucket is not None:
+    with span("get_schedule"):
+        spec = _coerce_spec(spec, legacy, "get_schedule")
+        if spec.dtype_bytes is None:
+            spec = dataclasses.replace(spec, dtype_bytes=4)
+        else:
+            spec = dataclasses.replace(spec, dtype_bytes=int(spec.dtype_bytes))
+        transpose = spec.transpose
+        a_eff = a.transpose() if transpose else a
+        cap = _resolve_width_cap(a_eff, spec.width_cap)
+        mk = sharded.mesh_key(spec.mesh)
+        sk = _shard_knobs_key(mk, spec.shard_combine, spec.shard_layout)
+        bucket = spec.bucket
+        if bucket is not None:
+            if spec.autotune:
+                raise ValueError("bucket= does not compose with autotune=True "
+                                 "(the sweep is per-content; bucket entries "
+                                 "are shape-keyed)")
+            if mk is not None:
+                raise ValueError("bucket= is single-device (v1); pass a "
+                                 "trivial mesh or none")
+            if transpose:
+                raise ValueError("bucket= is a serving (inference) knob; it "
+                                 "does not compose with transpose=True")
+            if spec.reorder is not None:
+                raise ValueError("bucket= does not compose with reorder= — "
+                                 "the incremental inspector patches by row "
+                                 "position, which a baked permutation would "
+                                 "silently invalidate")
         if spec.autotune:
-            raise ValueError("bucket= does not compose with autotune=True "
-                             "(the sweep is per-content; bucket entries "
-                             "are shape-keyed)")
-        if mk is not None:
-            raise ValueError("bucket= is single-device (v1); pass a "
-                             "trivial mesh or none")
-        if transpose:
-            raise ValueError("bucket= is a serving (inference) knob; it "
-                             "does not compose with transpose=True")
-        if spec.reorder is not None:
-            raise ValueError("bucket= does not compose with reorder= — "
-                             "the incremental inspector patches by row "
-                             "position, which a baked permutation would "
-                             "silently invalidate")
-    if spec.autotune:
-        return _autotune_schedule(a, b_col=b_col, c_col=c_col,
-                                  b_is_sparse=b_is_sparse, spec=spec,
-                                  cap=cap, mk=mk, sk=sk)
-    digest = _content_key(a)
-    keybase = ("bucket", bucket) if bucket is not None else digest
-    key = (keybase, b_col, c_col, b_is_sparse,
-           _spec_key(spec, cap=cap, mk=mk, sk=sk))
-    with _lock:
-        entry = _cache_get(_schedule_cache, key)
-        if entry is not None and (bucket is None
-                                  or entry.content_digest == digest):
-            entry.hits += 1
-            _stats["hits"] += 1
-            return entry
-    t0 = time.perf_counter()
-    sched = build_schedule(a_eff, b_col=b_col, c_col=c_col, p=spec.p,
-                           cache_size=spec.cache_size, ct_size=spec.ct_size,
-                           b_is_sparse=b_is_sparse,
-                           uniform_split=spec.uniform_split, width_cap=cap)
-    dsched = to_device_schedule(a_eff, sched, width_cap=cap)
-    tm = dsched.hbm_traffic_model(b_col, c_col,
-                                  dtype_bytes=spec.dtype_bytes)
-    a_sched = a_eff
-    applied = perm = inv = None
-    if spec.reorder is not None:
-        picked = _priced_reorder(a_eff, spec, cap=cap, b_col=b_col,
-                                 c_col=c_col, b_is_sparse=b_is_sparse,
-                                 base_tm=tm)
-        if picked is not None:
-            applied, perm, inv, a_sched, sched, dsched, tm = picked
-    tm["packed_ell_bytes"] = _packed_ell_bytes(a_sched, dsched, b_is_sparse,
-                                               spec.dtype_bytes)
-    shard = None
-    if mk is not None:
-        shard = _shard_for_mesh(a_sched, sched, dsched, mk, b_col=b_col,
-                                c_col=c_col, b_is_sparse=b_is_sparse,
-                                width_cap=cap, shard_combine=sk[0],
-                                shard_layout=sk[1],
-                                dtype_bytes=spec.dtype_bytes,
-                                overlap=spec.overlap, n_repl=spec.n_repl,
-                                serial_bytes=tm["fused_bytes"])
-        if shard is not None:
-            tm["sharded"] = shard.comm_model
-    entry = ScheduleEntry(sched=sched, dsched=dsched, b_col=b_col,
-                          c_col=c_col, b_is_sparse=b_is_sparse,
-                          inspector_s=time.perf_counter() - t0,
-                          traffic_model=tm, width_cap=cap,
-                          mesh_key=mk, shard=shard,
-                          content_digest=digest,
-                          bucket=bucket,
-                          transpose=transpose,
-                          dtype_bytes=spec.dtype_bytes,
-                          reorder=applied, reorder_perm=perm,
-                          reorder_inv=inv)
-    with _lock:
-        _stats["misses"] += 1
-        _cache_put(_schedule_cache, key, entry)
-    return entry
+            return _autotune_schedule(a, b_col=b_col, c_col=c_col,
+                                      b_is_sparse=b_is_sparse, spec=spec,
+                                      cap=cap, mk=mk, sk=sk)
+        digest = _content_key(a)
+        keybase = ("bucket", bucket) if bucket is not None else digest
+        key = (keybase, b_col, c_col, b_is_sparse,
+               _spec_key(spec, cap=cap, mk=mk, sk=sk))
+        with _lock:
+            entry = _cache_get(_schedule_cache, key)
+            if entry is not None and (bucket is None
+                                      or entry.content_digest == digest):
+                _stats["hits"] += 1
+                return entry
+        t0 = time.perf_counter()
+        with span("inspect"):
+            sched = build_schedule(a_eff, b_col=b_col, c_col=c_col,
+                                   p=spec.p, cache_size=spec.cache_size,
+                                   ct_size=spec.ct_size,
+                                   b_is_sparse=b_is_sparse,
+                                   uniform_split=spec.uniform_split,
+                                   width_cap=cap)
+            dsched = to_device_schedule(a_eff, sched, width_cap=cap)
+            tm = dsched.hbm_traffic_model(b_col, c_col,
+                                          dtype_bytes=spec.dtype_bytes)
+            a_sched = a_eff
+            applied = perm = inv = None
+            if spec.reorder is not None:
+                picked = _priced_reorder(a_eff, spec, cap=cap, b_col=b_col,
+                                         c_col=c_col, b_is_sparse=b_is_sparse,
+                                         base_tm=tm)
+                if picked is not None:
+                    applied, perm, inv, a_sched, sched, dsched, tm = picked
+            tm["packed_ell_bytes"] = _packed_ell_bytes(
+                a_sched, dsched, b_is_sparse, spec.dtype_bytes)
+            shard = None
+            if mk is not None:
+                shard = _shard_for_mesh(
+                    a_sched, sched, dsched, mk, b_col=b_col, c_col=c_col,
+                    b_is_sparse=b_is_sparse, width_cap=cap,
+                    shard_combine=sk[0], shard_layout=sk[1],
+                    dtype_bytes=spec.dtype_bytes, overlap=spec.overlap,
+                    n_repl=spec.n_repl, serial_bytes=tm["fused_bytes"])
+                if shard is not None:
+                    tm["sharded"] = shard.comm_model
+        entry = ScheduleEntry(sched=sched, dsched=dsched, b_col=b_col,
+                              c_col=c_col, b_is_sparse=b_is_sparse,
+                              traffic_model=tm, width_cap=cap,
+                              mesh_key=mk, shard=shard,
+                              content_digest=digest,
+                              bucket=bucket,
+                              transpose=transpose,
+                              dtype_bytes=spec.dtype_bytes,
+                              reorder=applied, reorder_perm=perm,
+                              reorder_inv=inv)
+        with _lock:
+            _stats["misses"] += 1
+            _stats["inspect_s"] += time.perf_counter() - t0
+            _cache_put(_schedule_cache, key, entry)
+        return entry
 
 
 def _priced_reorder(a_eff: CSR, spec: FusionSpec, *, cap, b_col: int,
@@ -720,11 +741,9 @@ def _autotune_schedule(a: CSR, *, b_col: int, c_col: int,
     with _lock:
         entry = _cache_get(_schedule_cache, key)
         if entry is not None:
-            entry.hits += 1
             _stats["hits"] += 1
             return entry
 
-    t0 = time.perf_counter()
     a_eff = a.transpose() if transpose else a
     cts = sorted(set(AUTOTUNE_CT_GRID) | {spec.ct_size, DEFAULT_CT_SIZE})
     if cap is None:
@@ -762,11 +781,8 @@ def _autotune_schedule(a: CSR, *, b_col: int, c_col: int,
     eligible = {k: e for k, e in candidates.items()
                 if traffic(e) <= traffic(anchor)}
     best_key = min(eligible, key=lambda k: score(eligible[k]))
-    # the autotuned entry's inspection cost is the whole sweep (what a
-    # fig10-style amortization argument must pay off), not one candidate
-    best = dataclasses.replace(eligible[best_key], hits=0,
-                               autotuned=best_key,
-                               inspector_s=time.perf_counter() - t0)
+    # each candidate's inspection already counted into inspect_s
+    best = dataclasses.replace(eligible[best_key], autotuned=best_key)
     if mk is not None:
         # the sweep's candidates are mesh-free; shard the winner (a fresh
         # traffic_model dict so the single-device candidate stays untouched).
@@ -795,7 +811,6 @@ def _autotune_schedule(a: CSR, *, b_col: int, c_col: int,
         # the duplicate work is bounded); only the published sweep counts
         existing = _cache_get(_schedule_cache, key)
         if existing is not None:
-            existing.hits += 1
             _stats["hits"] += 1
             return existing
         _stats["autotune_sweeps"] += 1
@@ -821,10 +836,15 @@ def _csr_ell(a: CSR, width_cap: int | None = None) -> Tuple[jax.Array, ...]:
     key = (_content_key(a), width_cap)
     with _ell_lock:
         ell = _cache_get(_ell_cache, key)
-        if ell is None:
-            with jax.ensure_compile_time_eval():
-                ell = fused_ops.csr_to_ell(a, width_cap=width_cap)
-            _cache_put(_ell_cache, key, ell, evict_key="ell_evictions")
+        if ell is not None:
+            _stats["ell_hits"] += 1
+            return ell
+        t0 = time.perf_counter()
+        with span("pack"), jax.ensure_compile_time_eval():
+            ell = fused_ops.csr_to_ell(a, width_cap=width_cap)
+        _stats["ell_misses"] += 1
+        _stats["pack_s"] += time.perf_counter() - t0
+        _cache_put(_ell_cache, key, ell, evict_key="ell_evictions")
     return ell
 
 
@@ -856,7 +876,10 @@ def schedule_cache_stats() -> dict:
     regression the serving tests pin.  ``transpose_entries`` counts the
     live backward-pass (``transpose=True``) schedules the custom_vjp
     training path inspected — one per (graph, shape) when the transpose
-    cache amortizes correctly."""
+    cache amortizes correctly.  ``inspect_s`` and ``pack_s`` are the host
+    seconds spent inspecting and packing ELLs since the last clear, and
+    ``ell_hits`` / ``ell_misses`` count the full-matrix ELL cache's
+    lookups."""
     with _lock, _ell_lock:
         mesh_entries = layout_1d = layout_15d = layout_25d = 0
         layout_fallback = bucket_entries = transpose_entries = 0
@@ -977,17 +1000,21 @@ def _wf1_pallas(ds: DeviceSchedule, d: jax.Array, d1: jax.Array,
     (hub-row tails past the width cap) as one scatter-add."""
     from ...kernels import ops as kops
     c_col = d.shape[1]
-    if ds.j_rows1.size:
-        t1, j1, w1 = ds.ell_cols1.shape
-        rows1 = kops.spmm_ell(
-            jnp.asarray(ds.ell_cols1.reshape(t1 * j1, w1)),
-            jnp.asarray(ds.ell_vals1.reshape(t1 * j1, w1), dtype), d1)
-        d = d.at[ds.j_rows1.reshape(-1)].set(rows1.reshape(-1, c_col),
-                                             mode="drop")
-    if ds.spill_rows1.size:
-        d = d.at[jnp.asarray(ds.spill_rows1)].add(
-            jnp.asarray(ds.spill_vals1, dtype)[:, None]
-            * d1[jnp.asarray(ds.spill_cols1)])
+    with scope("wf1"):
+        if ds.j_rows1.size:
+            t1, j1, w1 = ds.ell_cols1.shape
+            with span("upload"):
+                cols1 = jnp.asarray(ds.ell_cols1.reshape(t1 * j1, w1))
+                vals1 = jnp.asarray(ds.ell_vals1.reshape(t1 * j1, w1), dtype)
+            rows1 = kops.spmm_ell(cols1, vals1, d1)
+            d = d.at[ds.j_rows1.reshape(-1)].set(rows1.reshape(-1, c_col),
+                                                 mode="drop")
+        if ds.spill_rows1.size:
+            with span("upload"):
+                spill = (jnp.asarray(ds.spill_rows1),
+                         jnp.asarray(ds.spill_cols1),
+                         jnp.asarray(ds.spill_vals1, dtype))
+            d = fused_ops._spill_add(d, *spill, d1)
     return d
 
 
@@ -1002,12 +1029,15 @@ def _gemm_spmm_pallas(entry: ScheduleEntry, b: jax.Array,
     if b.shape[0] != ds.n_i:
         raise ValueError(f"b has {b.shape[0]} rows, schedule expects {ds.n_i}")
     b_pad = jnp.pad(b, ((0, n_t * t - b.shape[0]), (0, 0)))
-    d1, rows0 = kops.tile_fused_gemm_spmm_wf0(
-        jnp.asarray(ds.ell_cols0), jnp.asarray(ds.ell_vals0, b.dtype),
-        b_pad, c, t=t)
+    with span("upload"):
+        cols0 = jnp.asarray(ds.ell_cols0)
+        vals0 = jnp.asarray(ds.ell_vals0, b.dtype)
     c_col = c.shape[1]
-    d = jnp.zeros((ds.n_j, c_col), b.dtype).at[
-        ds.j_rows0.reshape(-1)].set(rows0.reshape(-1, c_col), mode="drop")
+    with scope("wf0"):
+        d1, rows0 = kops.tile_fused_gemm_spmm_wf0(cols0, vals0, b_pad, c, t=t)
+        d = jnp.zeros((ds.n_j, c_col), b.dtype).at[
+            ds.j_rows0.reshape(-1)].set(rows0.reshape(-1, c_col),
+                                        mode="drop")
     return _wf1_pallas(ds, d, d1[: ds.n_i], b.dtype)
 
 
@@ -1029,17 +1059,20 @@ def _spmm_spmm_pallas(entry: ScheduleEntry, a1: CSR,
     c_col = c.shape[1]
     o_cols, o_vals, spill_flat, spill_cols, spill_vals = fused_ops._op1_ell(
         a1, ds, width_cap=ds.width_cap)
+    with span("upload"):
+        op1 = (jnp.asarray(o_cols), jnp.asarray(o_vals, c.dtype))
+        spill = (jnp.asarray(spill_flat), jnp.asarray(spill_cols),
+                 jnp.asarray(spill_vals, c.dtype))
+        wf0 = (jnp.asarray(ds.ell_cols0), jnp.asarray(ds.ell_vals0, c.dtype))
     d1_spill = jnp.zeros((n_t * t, c_col), c.dtype)
     if spill_flat.size:
-        d1_spill = d1_spill.at[jnp.asarray(spill_flat)].add(
-            jnp.asarray(spill_vals, c.dtype)[:, None]
-            * c[jnp.asarray(spill_cols)])
-    d1, rows0 = kops.tile_fused_spmm_spmm_wf0(
-        jnp.asarray(o_cols), jnp.asarray(o_vals, c.dtype), d1_spill,
-        jnp.asarray(ds.ell_cols0), jnp.asarray(ds.ell_vals0, c.dtype),
-        c, t=t)
-    d = jnp.zeros((ds.n_j, c_col), c.dtype).at[
-        ds.j_rows0.reshape(-1)].set(rows0.reshape(-1, c_col), mode="drop")
+        d1_spill = fused_ops._spill_add(d1_spill, *spill, c)
+    with scope("wf0"):
+        d1, rows0 = kops.tile_fused_spmm_spmm_wf0(*op1, d1_spill, *wf0, c,
+                                                  t=t)
+        d = jnp.zeros((ds.n_j, c_col), c.dtype).at[
+            ds.j_rows0.reshape(-1)].set(rows0.reshape(-1, c_col),
+                                        mode="drop")
     return _wf1_pallas(ds, d, d1[: ds.n_i], c.dtype)
 
 
@@ -1053,77 +1086,78 @@ def _dispatch(a: CSR, b_or_a1, c, *, backend: str,
     with all sparse operands transposed (``D = aᵀ·(bᵀ·c)`` structurally —
     for the GeMM-SpMM pair only ``a`` is sparse, so ``D = aᵀ·(b·c)``),
     serving the backward pass from the transpose-keyed schedule entry."""
-    b_is_sparse = isinstance(b_or_a1, CSR)
-    transpose = spec.transpose
-    width_cap = spec.width_cap
-    a_run = a.transpose() if transpose else a
-    a1_run = (b_or_a1.transpose() if (b_is_sparse and transpose)
-              else b_or_a1)
+    with span("dispatch"):
+        b_is_sparse = isinstance(b_or_a1, CSR)
+        transpose = spec.transpose
+        width_cap = spec.width_cap
+        a_run = a.transpose() if transpose else a
+        a1_run = (b_or_a1.transpose() if (b_is_sparse and transpose)
+                  else b_or_a1)
 
-    def run_unfused():
-        if b_is_sparse:
-            hell_a = _csr_ell(a_run, _resolve_width_cap(a_run, width_cap))
-            hell_a1 = _csr_ell(a1_run,
-                               _resolve_width_cap(a1_run, width_cap))
-            return fused_ops.unfused_spmm_spmm(*hell_a, *hell_a1, c)
-        return fused_ops.unfused_gemm_spmm(
-            *_csr_ell(a_run, _resolve_width_cap(a_run, width_cap)),
-            jnp.asarray(b_or_a1), c)
+        def run_unfused():
+            if b_is_sparse:
+                hell_a = _csr_ell(a_run, _resolve_width_cap(a_run, width_cap))
+                hell_a1 = _csr_ell(a1_run,
+                                   _resolve_width_cap(a1_run, width_cap))
+                return fused_ops.unfused_spmm_spmm(*hell_a, *hell_a1, c)
+            return fused_ops.unfused_gemm_spmm(
+                *_csr_ell(a_run, _resolve_width_cap(a_run, width_cap)),
+                jnp.asarray(b_or_a1), c)
 
-    if backend == "unfused":
-        return run_unfused()          # no inspection needed for the baseline
+        if backend == "unfused":
+            return run_unfused()      # no inspection needed for the baseline
 
-    # the cost model's b_col is the width of the intermediate D1's inputs:
-    # dense-B column count for GeMM-SpMM, C's column count for SpMM-SpMM
-    # (op 1 is a1 @ c, so D1 is c_col wide and B's dense charge is c_col)
-    b_col = c.shape[1] if b_is_sparse else b_or_a1.shape[1]
-    if spec.dtype_bytes is None:
-        spec = dataclasses.replace(spec, dtype_bytes=(
-            cost_model.operand_dtype_bytes(c if b_is_sparse else b_or_a1,
-                                           c)))
-    entry = get_schedule(a, b_col=b_col, c_col=c.shape[1],
-                         b_is_sparse=b_is_sparse, spec=spec)
-    chosen = select_backend(entry) if backend == "auto" else backend
+        # the cost model's b_col is the width of the intermediate D1's inputs:
+        # dense-B column count for GeMM-SpMM, C's column count for SpMM-SpMM
+        # (op 1 is a1 @ c, so D1 is c_col wide and B's dense charge is c_col)
+        b_col = c.shape[1] if b_is_sparse else b_or_a1.shape[1]
+        if spec.dtype_bytes is None:
+            spec = dataclasses.replace(spec, dtype_bytes=(
+                cost_model.operand_dtype_bytes(c if b_is_sparse else b_or_a1,
+                                               c)))
+        entry = get_schedule(a, b_col=b_col, c_col=c.shape[1],
+                             b_is_sparse=b_is_sparse, spec=spec)
+        chosen = select_backend(entry) if backend == "auto" else backend
 
-    if chosen == "sharded" and entry.shard is None:
-        # trivial mesh, a non-uniform grid, or the priced single-device
-        # fallback: the XLA executor is the sharded path's one-device twin
-        chosen = "xla"
-    if chosen == "unfused":
-        return run_unfused()          # unpermuted operands — no reorder math
-    # an entry built under spec.reorder carries its permutation: permute
-    # the row-indexed operands in (P·B / P·A1 — jnp.take, so gradients
-    # flow through the linear permutation) and the output back out; the
-    # caller never sees the reordered frame
-    perm = entry.reorder_perm
-    if perm is not None:
-        if b_is_sparse:
-            a1_run = reorder.permute_rows_cached(a1_run, perm)
-    if chosen == "sharded":
-        if b_is_sparse:
-            d = sharded.sharded_spmm_spmm(entry.shard, entry.dsched,
-                                          spec.mesh, a1_run, c)
+        if chosen == "sharded" and entry.shard is None:
+            # trivial mesh, a non-uniform grid, or the priced single-device
+            # fallback: the XLA executor is the sharded path's one-device twin
+            chosen = "xla"
+        if chosen == "unfused":
+            return run_unfused()      # unpermuted operands — no reorder math
+        # an entry built under spec.reorder carries its permutation: permute
+        # the row-indexed operands in (P·B / P·A1 — jnp.take, so gradients
+        # flow through the linear permutation) and the output back out; the
+        # caller never sees the reordered frame
+        perm = entry.reorder_perm
+        if perm is not None:
+            if b_is_sparse:
+                a1_run = reorder.permute_rows_cached(a1_run, perm)
+        if chosen == "sharded":
+            if b_is_sparse:
+                d = sharded.sharded_spmm_spmm(entry.shard, entry.dsched,
+                                              spec.mesh, a1_run, c)
+            else:
+                b = jnp.asarray(b_or_a1)
+                if perm is not None:
+                    b = jnp.take(b, jnp.asarray(perm), axis=0)
+                d = sharded.sharded_gemm_spmm(entry.shard, spec.mesh, b, c)
+        elif b_is_sparse:
+            if chosen == "pallas":
+                d = _spmm_spmm_pallas(entry, a1_run, c)
+            else:
+                d = fused_ops.fused_spmm_spmm(entry.dsched, a1_run, c)
         else:
             b = jnp.asarray(b_or_a1)
             if perm is not None:
                 b = jnp.take(b, jnp.asarray(perm), axis=0)
-            d = sharded.sharded_gemm_spmm(entry.shard, spec.mesh, b, c)
-    elif b_is_sparse:
-        if chosen == "pallas":
-            d = _spmm_spmm_pallas(entry, a1_run, c)
-        else:
-            d = fused_ops.fused_spmm_spmm(entry.dsched, a1_run, c)
-    else:
-        b = jnp.asarray(b_or_a1)
+            if chosen == "pallas":
+                d = _gemm_spmm_pallas(entry, b, c)
+            else:
+                d = fused_ops.fused_gemm_spmm(entry.dsched, b, c)
         if perm is not None:
-            b = jnp.take(b, jnp.asarray(perm), axis=0)
-        if chosen == "pallas":
-            d = _gemm_spmm_pallas(entry, b, c)
-        else:
-            d = fused_ops.fused_gemm_spmm(entry.dsched, b, c)
-    if perm is not None:
-        d = jnp.take(d, jnp.asarray(entry.reorder_inv), axis=0)
-    return d
+            d = jnp.take(d, jnp.asarray(entry.reorder_inv), axis=0)
+        return d
 
 
 def _bwd_knobs(knobs: dict) -> dict:
@@ -1171,10 +1205,12 @@ def _gemm_spmm_diff(a: CSR, knobs: dict):
     def bwd(res, dd):
         b, c = res
         bk = _bwd_knobs(knobs)
-        db = tile_fused_matmul(a, dd, c.T, **bk)
-        g1 = _transpose_spmm(a, dd, transpose=bk["spec"].transpose,
-                             width_cap=knobs["spec"].width_cap)
-        dc = b.T.astype(g1.dtype) @ g1
+        with scope("backward"):
+            db = tile_fused_matmul(a, dd, c.T, **bk)
+            g1 = _transpose_spmm(a, dd, transpose=bk["spec"].transpose,
+                                 width_cap=knobs["spec"].width_cap)
+            with scope("gemm"):
+                dc = b.T.astype(g1.dtype) @ g1
         return jnp.asarray(db, b.dtype), jnp.asarray(dc, c.dtype)
 
     f = jax.custom_vjp(primal)
@@ -1198,7 +1234,8 @@ def _spmm_spmm_diff(a: CSR, a1: CSR, knobs: dict):
         return primal(c), None
 
     def bwd(_, dd):
-        dc = tile_fused_matmul(a1, a, dd, **_bwd_knobs(knobs))
+        with scope("backward"):
+            dc = tile_fused_matmul(a1, a, dd, **_bwd_knobs(knobs))
         return (jnp.asarray(dc, dd.dtype),)
 
     f = jax.custom_vjp(primal)

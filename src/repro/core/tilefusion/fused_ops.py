@@ -9,11 +9,13 @@ prior-work baselines of Figure 6/12, adapted as in paper §4.1.3.
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...trace import scope, span
 from ..sparse.formats import (CSR, HybridELL, TileELL, csr_content_digest,
                               ell_slot_coords)
 from .schedule import DeviceSchedule
@@ -37,9 +39,10 @@ def _ell_rows(cols, vals, table):
     def body(acc, wv):
         return acc + term(*wv), None
 
-    acc, _ = jax.lax.scan(body, term(cols[..., 0], vals[..., 0]),
-                          (jnp.moveaxis(cols[..., 1:], -1, 0),
-                           jnp.moveaxis(vals[..., 1:], -1, 0)))
+    with scope("ell_body"):
+        acc, _ = jax.lax.scan(body, term(cols[..., 0], vals[..., 0]),
+                              (jnp.moveaxis(cols[..., 1:], -1, 0),
+                               jnp.moveaxis(vals[..., 1:], -1, 0)))
     return acc
 
 
@@ -49,8 +52,9 @@ def _spill_add(d, spill_rows, spill_cols, spill_vals, table):
     The hybrid-ELL tail pass: called after the body's ``.set`` scatter so a
     capped row's total is body + tail.  Zero lanes are a no-op (traced
     statically — callers may skip the call entirely when size is 0)."""
-    return d.at[spill_rows].add(
-        spill_vals.astype(table.dtype)[:, None] * table[spill_cols])
+    with scope("spill"):
+        return d.at[spill_rows].add(
+            spill_vals.astype(table.dtype)[:, None] * table[spill_cols])
 
 
 # --------------------------------------------------------------------------
@@ -65,27 +69,30 @@ def _fused_gemm_spmm_impl(b_pad, c, i_starts, j_rows0, cols0, vals0,
     # ---- wavefront 0: one vmapped step per fused tile ----
     def tile_fn(i_start, j_rows, cols, vals):
         b_t = jax.lax.dynamic_slice(b_pad, (i_start, 0), (t_pad, b_pad.shape[1]))
-        d1_t = b_t @ c                                   # GeMM rows of the tile
+        with scope("gemm"):
+            d1_t = b_t @ c                           # GeMM rows of the tile
         rows = _ell_rows(cols, vals, d1_t)               # fused SpMM rows
         return d1_t, rows
 
-    d1_tiles, rows0 = jax.vmap(tile_fn)(i_starts, j_rows0, cols0, vals0)
+    with scope("wf0"):
+        d1_tiles, rows0 = jax.vmap(tile_fn)(i_starts, j_rows0, cols0, vals0)
 
-    # stitch D1 (disjoint contiguous ranges; padded rows dropped)
-    row_idx = (i_starts[:, None] + jnp.arange(t_pad)[None, :]).reshape(-1)
-    row_idx = jnp.where(row_idx < n_i, row_idx, n_i)     # pad rows -> drop
-    d1 = jnp.zeros((n_i, c_col), c.dtype).at[row_idx].set(
-        d1_tiles.reshape(-1, c_col), mode="drop")
-    d = jnp.zeros((n_j, c_col), c.dtype).at[j_rows0.reshape(-1)].set(
-        rows0.reshape(-1, c_col), mode="drop")
+        # stitch D1 (disjoint contiguous ranges; padded rows dropped)
+        row_idx = (i_starts[:, None] + jnp.arange(t_pad)[None, :]).reshape(-1)
+        row_idx = jnp.where(row_idx < n_i, row_idx, n_i)  # pad rows -> drop
+        d1 = jnp.zeros((n_i, c_col), c.dtype).at[row_idx].set(
+            d1_tiles.reshape(-1, c_col), mode="drop")
+        d = jnp.zeros((n_j, c_col), c.dtype).at[j_rows0.reshape(-1)].set(
+            rows0.reshape(-1, c_col), mode="drop")
 
     # ---- barrier; wavefront 1: global gather over D1 (body, then spill) ----
-    if j_rows1.shape[0]:
-        rows1 = _ell_rows(cols1, vals1, d1)              # (T1, j1_max, c_col)
-        d = d.at[j_rows1.reshape(-1)].set(
-            rows1.reshape(-1, c_col), mode="drop")
-    if srows1.shape[0]:
-        d = _spill_add(d, srows1, scols1, svals1, d1)
+    with scope("wf1"):
+        if j_rows1.shape[0]:
+            rows1 = _ell_rows(cols1, vals1, d1)          # (T1, j1_max, c_col)
+            d = d.at[j_rows1.reshape(-1)].set(
+                rows1.reshape(-1, c_col), mode="drop")
+        if srows1.shape[0]:
+            d = _spill_add(d, srows1, scols1, svals1, d1)
     return d
 
 
@@ -97,17 +104,20 @@ def _fused_gemm_spmm_uniform(b_pad, c, j_rows0, cols0, vals0,
     padding waste — the executor twin of the Pallas kernel's grid."""
     c_col = c.shape[1]
     n_t = b_pad.shape[0] // t
-    d1_tiles = jnp.einsum("tkb,bc->tkc", b_pad.reshape(n_t, t, -1), c)
-    rows0 = jax.vmap(_ell_rows)(cols0, vals0, d1_tiles)
-    d1 = d1_tiles.reshape(n_t * t, c_col)
-    d = jnp.zeros((n_j, c_col), c.dtype).at[j_rows0.reshape(-1)].set(
-        rows0.reshape(-1, c_col), mode="drop")
-    if j_rows1.shape[0]:
-        rows1 = _ell_rows(cols1, vals1, d1[:n_i])
-        d = d.at[j_rows1.reshape(-1)].set(rows1.reshape(-1, c_col),
-                                          mode="drop")
-    if srows1.shape[0]:
-        d = _spill_add(d, srows1, scols1, svals1, d1[:n_i])
+    with scope("wf0"):
+        with scope("gemm"):
+            d1_tiles = jnp.einsum("tkb,bc->tkc", b_pad.reshape(n_t, t, -1), c)
+        rows0 = jax.vmap(_ell_rows)(cols0, vals0, d1_tiles)
+        d1 = d1_tiles.reshape(n_t * t, c_col)
+        d = jnp.zeros((n_j, c_col), c.dtype).at[j_rows0.reshape(-1)].set(
+            rows0.reshape(-1, c_col), mode="drop")
+    with scope("wf1"):
+        if j_rows1.shape[0]:
+            rows1 = _ell_rows(cols1, vals1, d1[:n_i])
+            d = d.at[j_rows1.reshape(-1)].set(rows1.reshape(-1, c_col),
+                                              mode="drop")
+        if srows1.shape[0]:
+            d = _spill_add(d, srows1, scols1, svals1, d1[:n_i])
     return d
 
 
@@ -124,32 +134,33 @@ def _is_uniform(dsched: DeviceSchedule) -> bool:
                 and (ln[:-1] == t).all())
 
 
-def _wf1_spill_args(dsched: DeviceSchedule, dtype):
-    return (jnp.asarray(dsched.spill_rows1), jnp.asarray(dsched.spill_cols1),
-            jnp.asarray(dsched.spill_vals1, dtype))
+def _schedule_args(dsched: DeviceSchedule, dtype) -> tuple:
+    """The schedule's wavefront arrays as device arrays (values in
+    ``dtype``): wf0 rows, cols, vals; wf1 rows, cols, vals; spill rows,
+    cols, vals.  The host-to-device copy is the ``repro.upload`` span."""
+    with span("upload"):
+        return (jnp.asarray(dsched.j_rows0), jnp.asarray(dsched.ell_cols0),
+                jnp.asarray(dsched.ell_vals0, dtype),
+                jnp.asarray(dsched.j_rows1), jnp.asarray(dsched.ell_cols1),
+                jnp.asarray(dsched.ell_vals1, dtype),
+                jnp.asarray(dsched.spill_rows1),
+                jnp.asarray(dsched.spill_cols1),
+                jnp.asarray(dsched.spill_vals1, dtype))
 
 
 def fused_gemm_spmm(dsched: DeviceSchedule, b: jax.Array, c: jax.Array) -> jax.Array:
+    args = _schedule_args(dsched, c.dtype)
     if _is_uniform(dsched):
         t = dsched.t_pad
         n_t = dsched.n_tiles0
         b_pad = jnp.pad(b, ((0, n_t * t - b.shape[0]), (0, 0)))
         return _fused_gemm_spmm_uniform(
-            b_pad, c, jnp.asarray(dsched.j_rows0),
-            jnp.asarray(dsched.ell_cols0),
-            jnp.asarray(dsched.ell_vals0, c.dtype),
-            jnp.asarray(dsched.j_rows1), jnp.asarray(dsched.ell_cols1),
-            jnp.asarray(dsched.ell_vals1, c.dtype),
-            *_wf1_spill_args(dsched, c.dtype),
-            t=t, n_i=dsched.n_i, n_j=dsched.n_j)
+            b_pad, c, *args, t=t, n_i=dsched.n_i, n_j=dsched.n_j)
     b_pad = jnp.pad(b, ((0, dsched.t_pad), (0, 0)))
+    with span("upload"):
+        i_starts = jnp.asarray(dsched.i_starts)
     return _fused_gemm_spmm_impl(
-        b_pad, c,
-        jnp.asarray(dsched.i_starts), jnp.asarray(dsched.j_rows0),
-        jnp.asarray(dsched.ell_cols0), jnp.asarray(dsched.ell_vals0, c.dtype),
-        jnp.asarray(dsched.j_rows1), jnp.asarray(dsched.ell_cols1),
-        jnp.asarray(dsched.ell_vals1, c.dtype),
-        *_wf1_spill_args(dsched, c.dtype),
+        b_pad, c, i_starts, *args,
         t_pad=dsched.t_pad, n_i=dsched.n_i, n_j=dsched.n_j)
 
 
@@ -166,21 +177,24 @@ def _fused_spmm_spmm_impl(c, i_starts, op1_cols, op1_vals, d1_spill,
         rows = _ell_rows(cols, vals, d1_t)               # in-tile gather
         return d1_t, rows
 
-    d1_tiles, rows0 = jax.vmap(tile_fn)(
-        i_starts, op1_cols, op1_vals, d1_spill, j_rows0, cols0, vals0)
+    with scope("wf0"):
+        d1_tiles, rows0 = jax.vmap(tile_fn)(
+            i_starts, op1_cols, op1_vals, d1_spill, j_rows0, cols0, vals0)
 
-    row_idx = (i_starts[:, None] + jnp.arange(t_pad)[None, :]).reshape(-1)
-    row_idx = jnp.where(row_idx < n_i, row_idx, n_i)
-    d1 = jnp.zeros((n_i, c_col), c.dtype).at[row_idx].set(
-        d1_tiles.reshape(-1, c_col), mode="drop")
-    d = jnp.zeros((n_j, c_col), c.dtype).at[j_rows0.reshape(-1)].set(
-        rows0.reshape(-1, c_col), mode="drop")
+        row_idx = (i_starts[:, None] + jnp.arange(t_pad)[None, :]).reshape(-1)
+        row_idx = jnp.where(row_idx < n_i, row_idx, n_i)
+        d1 = jnp.zeros((n_i, c_col), c.dtype).at[row_idx].set(
+            d1_tiles.reshape(-1, c_col), mode="drop")
+        d = jnp.zeros((n_j, c_col), c.dtype).at[j_rows0.reshape(-1)].set(
+            rows0.reshape(-1, c_col), mode="drop")
 
-    if j_rows1.shape[0]:
-        rows1 = _ell_rows(cols1, vals1, d1)
-        d = d.at[j_rows1.reshape(-1)].set(rows1.reshape(-1, c_col), mode="drop")
-    if srows1.shape[0]:
-        d = _spill_add(d, srows1, scols1, svals1, d1)
+    with scope("wf1"):
+        if j_rows1.shape[0]:
+            rows1 = _ell_rows(cols1, vals1, d1)
+            d = d.at[j_rows1.reshape(-1)].set(rows1.reshape(-1, c_col),
+                                              mode="drop")
+        if srows1.shape[0]:
+            d = _spill_add(d, srows1, scols1, svals1, d1)
     return d
 
 
@@ -203,7 +217,11 @@ def _op1_ell(a1: CSR, dsched: DeviceSchedule, width_cap: int | None = None):
     memo = getattr(dsched, "_op1_pack_memo", None)
     if memo is not None and memo[0] == memo_key:
         return memo[1]
-    packed = _op1_ell_build(a1, dsched, width_cap)
+    t0 = time.perf_counter()
+    with span("pack"):
+        packed = _op1_ell_build(a1, dsched, width_cap)
+    from . import api   # api imports this module: bind at call time
+    api.count_pack(time.perf_counter() - t0)
     object.__setattr__(dsched, "_op1_pack_memo", (memo_key, packed))
     return packed
 
@@ -239,20 +257,18 @@ def fused_spmm_spmm(dsched: DeviceSchedule, a1: CSR, c: jax.Array) -> jax.Array:
         a1, dsched, width_cap=dsched.width_cap)
     n_t, t_pad = dsched.n_tiles0, dsched.t_pad
     c_col = c.shape[1]
+    args = _schedule_args(dsched, c.dtype)
+    with span("upload"):
+        i_starts = jnp.asarray(dsched.i_starts)
+        op1 = (jnp.asarray(cols), jnp.asarray(vals, c.dtype))
+        spill = (jnp.asarray(spill_flat), jnp.asarray(spill_cols),
+                 jnp.asarray(spill_vals, c.dtype))
     # spill delta on the flattened padded D1 tiles, zero when nothing spills
     d1_spill = jnp.zeros((n_t * t_pad, c_col), c.dtype)
     if spill_flat.size:
-        d1_spill = _spill_add(d1_spill, jnp.asarray(spill_flat),
-                              jnp.asarray(spill_cols),
-                              jnp.asarray(spill_vals, c.dtype), c)
+        d1_spill = _spill_add(d1_spill, *spill, c)
     return _fused_spmm_spmm_impl(
-        c, jnp.asarray(dsched.i_starts), jnp.asarray(cols),
-        jnp.asarray(vals, c.dtype), d1_spill.reshape(n_t, t_pad, c_col),
-        jnp.asarray(dsched.j_rows0), jnp.asarray(dsched.ell_cols0),
-        jnp.asarray(dsched.ell_vals0, c.dtype),
-        jnp.asarray(dsched.j_rows1), jnp.asarray(dsched.ell_cols1),
-        jnp.asarray(dsched.ell_vals1, c.dtype),
-        *_wf1_spill_args(dsched, c.dtype),
+        c, i_starts, *op1, d1_spill.reshape(n_t, t_pad, c_col), *args,
         t_pad=dsched.t_pad, n_i=dsched.n_i, n_j=dsched.n_j)
 
 
@@ -290,7 +306,8 @@ def spmm_hybrid(cols, vals, srows, scols, svals, x):
 
 @jax.jit
 def unfused_gemm_spmm(cols, vals, srows, scols, svals, b, c):
-    d1 = b @ c
+    with scope("gemm"):
+        d1 = b @ c
     return spmm_hybrid(cols, vals, srows, scols, svals, d1)
 
 

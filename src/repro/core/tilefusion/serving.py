@@ -50,6 +50,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from ...trace import span
 from ..sparse.formats import (CSR, csr_content_digest, csr_gather_rows,
                               ell_slot_coords)
 from . import api, cost_model, fused_ops
@@ -265,9 +266,9 @@ def incremental_update(a_old: CSR, entry: api.ScheduleEntry, a_new: CSR,
     tm = ds_new.hbm_traffic_model(entry.b_col, entry.c_col)
     tm["packed_ell_bytes"] = api._packed_ell_bytes(a_new, ds_new,
                                                    entry.b_is_sparse)
+    api.count_inspect(time.perf_counter() - t0)
     return dataclasses.replace(
-        entry, sched=new_sched, dsched=ds_new, traffic_model=tm, hits=0,
-        inspector_s=time.perf_counter() - t0,
+        entry, sched=new_sched, dsched=ds_new, traffic_model=tm,
         content_digest=csr_content_digest(a_new))
 
 
@@ -365,8 +366,10 @@ class ServingTier:
             dirty = csr_dirty_rows(res.a, ap)
             limit = max(self.max_dirty_frac * ap.n_rows, 1.0)
             if dirty is not None and dirty.size <= limit:
-                patched = incremental_update(res.a, res.entry, ap, dirty,
-                                             cache_size=self.cache_size)
+                with span("inspect"):
+                    patched = incremental_update(
+                        res.a, res.entry, ap, dirty,
+                        cache_size=self.cache_size)
                 if patched is not None:
                     api.store_bucket_schedule(
                         patched, bucket=bucket, patched=True,

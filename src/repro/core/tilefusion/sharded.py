@@ -95,6 +95,7 @@ import dataclasses
 
 import numpy as np
 
+from ...trace import scope
 from ..sparse.formats import CSR, csr_content_digest
 from . import cost_model, fused_ops
 from .schedule import DeviceSchedule
@@ -647,10 +648,11 @@ def _shard_executor(shard: ShardedSchedule, mesh, kind: str):
         this fiber's send rows over the row axis and scatter them into the
         layer's table at the schedule's positions."""
         cc = contrib.shape[-1]
-        gathered = jax.lax.all_gather(contrib, row_axes)   # (S, Hs, cc)
-        flat = gathered.reshape(-1, cc)
-        base = jnp.zeros((hp, cc), dtype)
-        return base.at[_layer_pos()].set(flat, mode="drop")
+        with scope("halo"):
+            gathered = jax.lax.all_gather(contrib, row_axes)  # (S, Hs, cc)
+            flat = gathered.reshape(-1, cc)
+            base = jnp.zeros((hp, cc), dtype)
+            return base.at[_layer_pos()].set(flat, mode="drop")
 
     def _mask_wf0(d):
         """Only depth layer 0 emits the (replicated) wavefront-0 rows —
@@ -663,11 +665,12 @@ def _shard_executor(shard: ShardedSchedule, mesh, kind: str):
         """Root stage: psum partials over the depth axes, then the output
         combine — psum over the row axis, or (owner-disjoint partials)
         emit the shard's own block."""
-        if reduce_scatter:
-            if depth_axes:
-                d = jax.lax.psum(d, tuple(depth_axes))
-            return d
-        return jax.lax.psum(d, tuple(row_axes) + tuple(depth_axes))
+        with scope("combine"):
+            if reduce_scatter:
+                if depth_axes:
+                    d = jax.lax.psum(d, tuple(depth_axes))
+                return d
+            return jax.lax.psum(d, tuple(row_axes) + tuple(depth_axes))
 
     def wf1_apply(d, halo, rows1_s, cols1_s, vals1_s,
                   srows_s, scols_s, svals_s):
@@ -678,8 +681,9 @@ def _shard_executor(shard: ShardedSchedule, mesh, kind: str):
             d = d.at[rows1_s.reshape(-1)].set(
                 rows1.reshape(-1, c_col), mode="drop")
         if sp_l:
-            d = d.at[srows_s].add(
-                svals_s.astype(d.dtype)[:, None] * halo[scols_s])
+            with scope("spill"):
+                d = d.at[srows_s].add(
+                    svals_s.astype(d.dtype)[:, None] * halo[scols_s])
         return d
 
     def _finish_body(d1_flat, c, halo, rows0_s, cols0_s, vals0_s, rows1_s,
@@ -709,13 +713,15 @@ def _shard_executor(shard: ShardedSchedule, mesh, kind: str):
         composed slot indices, so the deferred exchange never pays the
         per-call halo-table scatter the eager path does."""
         contrib = d1_flat[send_local_s]                    # (Hs, c_col)
-        gathered = jax.lax.all_gather(contrib, row_axes)   # (S, Hs, cc)
+        with scope("halo"):
+            gathered = jax.lax.all_gather(contrib, row_axes)  # (S, Hs, cc)
         return gathered.reshape(-1, contrib.shape[-1])
 
     def per_shard_gemm(b_blk, c, rows0_s, cols0_s, vals0_s, rows1_s,
                        cols1_s, vals1_s, srows_s, scols_s, svals_s,
                        send_local_s):
-        d1_flat = b_blk @ c                                # (T0s*t, c_col)
+        with scope("gemm"):
+            d1_flat = b_blk @ c                            # (T0s*t, c_col)
         halo = _issue_gather(d1_flat, send_local_s) if async_halo else None
         out = _finish_body(d1_flat, c, halo, rows0_s, cols0_s, vals0_s,
                            rows1_s, cols1_s, vals1_s, srows_s, scols_s,
@@ -886,8 +892,8 @@ def sharded_spmm_spmm(shard: ShardedSchedule, dsched: DeviceSchedule,
     n_pad = shard.n_tiles0 * shard.t_pad
     d1_spill = jnp.zeros((n_pad, cc_pad), c.dtype)
     if n_spill:
-        d1_spill = d1_spill.at[spill_flat].add(
-            spill_vals.astype(c.dtype)[:, None] * c[spill_cols])
+        d1_spill = fused_ops._spill_add(d1_spill, spill_flat, spill_cols,
+                                        spill_vals, c)
     d1_spill_blk = d1_spill[_device_const(shard, "row_map")]
     run = _shard_executor(shard, mesh, "spmm")
     return _finish(shard, run(o_cols_s, o_vals_s, d1_spill_blk, c), c_col)

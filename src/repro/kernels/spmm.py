@@ -70,6 +70,7 @@ def _spmm_ell(cols: jax.Array, vals: jax.Array, x: jax.Array,
         out_shape=jax.ShapeDtypeStruct((n_rows + pad, c), x.dtype),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="spmm",
     )(cols, vals, x)
     return out[:n_rows] if pad else out
 

@@ -122,4 +122,5 @@ def _tile_fused_gemm_spmm_wf0(cols0, vals0, b, c, *, t: int, interpret: bool):
         out_shape=out_shape,
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="tile_fused_gemm_spmm",
     )(cols0, vals0, b, c)

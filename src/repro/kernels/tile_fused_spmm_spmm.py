@@ -129,4 +129,5 @@ def _tile_fused_spmm_spmm_wf0(op1_cols, op1_vals, d1_spill, cols0, vals0, c,
         out_shape=out_shape,
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="tile_fused_spmm_spmm",
     )(op1_cols, op1_vals, d1_spill, cols0, vals0, c)

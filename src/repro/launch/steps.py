@@ -7,6 +7,7 @@ import jax.numpy as jnp
 
 from ..models import transformer as T
 from ..optim import adamw
+from ..trace import scope
 
 
 def cross_entropy(logits, labels):
@@ -52,7 +53,8 @@ def make_gcn_train_step(model, *, lr: float = 0.3, fused: bool = True,
         loss, grads = jax.value_and_grad(
             lambda p: model.loss(p, x, y, fused=fused, backend=backend,
                                  mesh=mesh))(params)
-        return [w - lr * g for w, g in zip(params, grads)], loss
+        with scope("sgd.update"):
+            return [w - lr * g for w, g in zip(params, grads)], loss
     return jax.jit(step) if jit else step
 
 

@@ -14,6 +14,7 @@ import numpy as np
 
 from ..core.sparse.formats import CSR
 from ..core.tilefusion import api
+from ..trace import scope
 
 
 def normalize_adjacency(a: CSR) -> CSR:
@@ -126,13 +127,16 @@ class GCN:
         spec = (dataclasses.replace(self.spec, mesh=mesh)
                 if mesh is not None else self.spec)
         for i, w in enumerate(params):
-            h = api.tile_fused_matmul(self.adj, x, w, backend=be, spec=spec)
-            x = jax.nn.relu(h) if i < len(params) - 1 else h
+            with scope(f"gcn.layer{i}"):
+                h = api.tile_fused_matmul(self.adj, x, w, backend=be,
+                                          spec=spec)
+                x = jax.nn.relu(h) if i < len(params) - 1 else h
         return x
 
     def loss(self, params, x, labels, *, fused: bool = True,
              backend: str = None, mesh=None):
         logits = self.forward(params, x, fused=fused, backend=backend,
                               mesh=mesh)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
+        with scope("gcn.loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))

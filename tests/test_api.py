@@ -1,7 +1,10 @@
 """Unified dispatch API: inspector cache, backend overrides, cost model."""
 import dataclasses
+import re
+import types
 import warnings
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +12,8 @@ import pytest
 from repro.core.sparse.formats import CSR
 from repro.core.sparse.random import banded_spd, powerlaw_graph
 from repro.core.tilefusion import api, fused_ref
+from repro.launch.steps import make_gcn_train_step
+from repro.models.gcn import GCN
 
 
 @pytest.fixture(autouse=True)
@@ -326,3 +331,57 @@ def test_spec_validates_overlap_and_n_repl():
                           spec=api.FusionSpec(overlap=False, n_repl=None))
     assert e2 is e1
     assert api.schedule_cache_stats()["spec_entries"] == 1
+
+
+def test_inspect_and_pack_counters_after_a_miss_then_a_hit():
+    a = powerlaw_graph(512, 8, seed=3)
+    b, c = jnp.ones((512, 16)), jnp.ones((16, 8))
+    for _ in range(2):                    # a miss, then a hit
+        api.get_schedule(a, b_col=16, c_col=8)
+        api.tile_fused_matmul(a, b, c, backend="unfused")
+    st = api.schedule_cache_stats()
+    assert st["inspect_s"] > 0 and st["pack_s"] > 0
+    assert (st["misses"], st["ell_misses"]) == (1, 1)
+    assert st["hits"] >= 1 and st["ell_hits"] >= 1
+    api.clear_schedule_cache()
+    st = api.schedule_cache_stats()
+    assert st["inspect_s"] == st["pack_s"] == 0
+    assert st["ell_hits"] == st["ell_misses"] == 0
+
+
+def test_op1_pack_counts_into_pack_s():
+    a = banded_spd(256, 4, seed=2)
+    c = jnp.ones((256, 8))
+    api.tile_fused_matmul(a, a, c, backend="xla")
+    st = api.schedule_cache_stats()
+    assert st["pack_s"] > 0 and st["ell_misses"] == 0
+    pack_s = st["pack_s"]
+    api.tile_fused_matmul(a, a, c, backend="xla")   # the pack is memoized
+    assert api.schedule_cache_stats()["pack_s"] == pack_s
+
+
+@pytest.mark.parametrize("backend,graph,inner", [
+    ("unfused", "powerlaw", {"repro.ell_body", "repro.spill", "repro.gemm"}),
+    ("xla", "banded", {"repro.wf0", "repro.wf1", "repro.ell_body",
+                       "repro.gemm"}),
+])
+def test_scopes_name_every_layer_of_the_compiled_gcn_step(backend, graph,
+                                                          inner):
+    """Each layer boundary of the GCN step is a ``repro.`` scope in the
+    optimized HLO's ``op_name``, backward included."""
+    n = 512
+    adj = (powerlaw_graph(n, 8, seed=1) if graph == "powerlaw"
+           else banded_spd(n, 4, seed=1))
+    cfg = types.SimpleNamespace(n_nodes=n, in_dim=16, hidden_dim=32,
+                                out_dim=8, n_layers=2)
+    model = GCN(cfg, adj, ct_size=128)
+    step = make_gcn_train_step(model, lr=0.1, backend=backend)
+    params = model.init_params(jax.random.PRNGKey(0))
+    text = step.lower(params, jnp.ones((n, 16)),
+                      jnp.zeros((n,), jnp.int32)).compile().as_text()
+    paths = {tuple(re.findall(r"repro\.[\w.]+", name))
+             for name in re.findall(r'op_name="([^"]*)"', text)}
+    scoped = {s for p in paths for s in p}
+    assert {"repro.gcn.layer0", "repro.gcn.layer1", "repro.gcn.loss",
+            "repro.sgd.update", "repro.backward"} | inner <= scoped
+    assert ("repro.gcn.layer0", "repro.backward") in {p[:2] for p in paths}
