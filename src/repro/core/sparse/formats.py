@@ -235,8 +235,11 @@ class HybridELL:
       * **spill lanes** — the tail entries of rows wider than the cap, as
         flat COO triples ``(spill_rows, spill_cols, spill_vals)`` sorted by
         row.  ``spill_rows[k]`` indexes the *packed row set* (position in
-        the ``rows`` argument of ``from_csr_rows``), so consumers apply the
-        spill with one scatter-add after the dense ELL body pass.
+        the ``rows`` argument of ``from_csr_rows``).  The full-matrix
+        unfused SpMM folds them per row (``spill_fold``: virtual rows of
+        the body's width, one sorted update each); the tile executors,
+        which address lanes by tile-padded position, scatter-add them
+        after the dense ELL body pass.
 
     Total storage is ``n_rows * width + n_spill`` value slots, bounded by
     the typical-degree mass instead of the max degree — the SpArch-style
@@ -290,6 +293,41 @@ class HybridELL:
             spill_rows=r[sp].astype(np.int32),
             spill_cols=a.indices[flat[sp]].astype(np.int32),
             spill_vals=a.data[flat[sp]].astype(np.float64))
+
+    def spill_fold(self) -> "SpillFold":
+        """The spill lanes as virtual rows (``SpillFold``): each spilled
+        row's tail cut into rows of the body's width, the last one padded
+        with col 0 / val 0, each tagged with the row it adds to.  Lanes are
+        sorted by row, so the tags are too.  O(lanes), no per-row loop."""
+        n, w = self.cols.shape
+        lanes = np.bincount(self.spill_rows, minlength=n)
+        rows = np.flatnonzero(lanes)
+        parts = -(-lanes[rows] // w)            # virtual rows per row
+        owner, pos = ell_slot_coords(lanes[rows])
+        vrow = (np.cumsum(parts) - parts)[owner] + pos // w
+        vcols = np.zeros((int(parts.sum()), w), np.int32)
+        vvals = np.zeros((int(parts.sum()), w), np.float64)
+        vcols[vrow, pos % w] = self.spill_cols
+        vvals[vrow, pos % w] = self.spill_vals
+        return SpillFold(vcols, vvals, np.repeat(rows, parts).astype(np.int32))
+
+
+@dataclasses.dataclass(frozen=True)
+class SpillFold:
+    """A ``HybridELL``'s spill lanes, folded per row instead of per lane.
+
+    ``vcols``/``vvals`` are the virtual rows, an ELL of the body's width;
+    ``rows[v]`` is the row virtual row ``v`` adds to, ascending.  A device
+    pass is the ELL's SpMM plus one sorted update per virtual row, where
+    per-lane COO takes one gather and one scatter update per lane."""
+
+    vcols: np.ndarray           # int32 (n_virtual, width)
+    vvals: np.ndarray           # float (n_virtual, width)
+    rows: np.ndarray            # int32 (n_virtual,), ascending
+
+    @property
+    def n_virtual(self) -> int:
+        return int(self.rows.shape[0])
 
 
 def block_csr_pattern(a: CSR, block: int) -> CSR:

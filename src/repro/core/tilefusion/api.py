@@ -25,9 +25,12 @@ owns the two decisions every call site used to repeat by hand:
 packed by the shared ``formats.HybridELL`` packer with a width cap —
 "auto" picks the traffic-optimal cap from the degree distribution, so one
 max-degree hub row of a power-law graph no longer inflates the padded
-allocation; the capped tails travel as COO spill lanes applied with one
-scatter-add.  The resolved cap is part of the schedule and ELL cache keys,
-and the autotune sweep tries candidate caps alongside tile sizes.
+allocation.  The capped tails travel as COO spill lanes: the unfused
+full-matrix SpMM folds them per row (virtual rows of the body's width,
+one sorted update each), the fused, Pallas and sharded executors
+scatter-add them lane by lane.  The resolved cap is part
+of the schedule and ELL cache keys, and the autotune sweep tries
+candidate caps alongside tile sizes.
 
 **Tile-size autotuning (``autotune=True``).**  ``get_schedule`` /
 ``tile_fused_matmul`` accept ``autotune=True`` to sweep a small
@@ -48,7 +51,8 @@ oldest-first instead of growing without bound.
 ``schedule_cache_stats()`` reports hits/misses/evictions plus live entry
 counts of both caches, and the host seconds spent inspecting
 (``inspect_s``) and packing ELLs (``pack_s``, with ``ell_hits`` /
-``ell_misses``).  The same work shows in a profile as the host spans
+``ell_misses``, and the spill fold's ``spill_lanes`` and
+``spill_virtual_rows``).  The same work shows in a profile as the host spans
 ``repro.get_schedule``, ``repro.inspect``, ``repro.pack``,
 ``repro.digest`` and ``repro.dispatch`` (``repro.trace``).
 
@@ -102,7 +106,6 @@ import dataclasses
 import os
 import threading
 import time
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -340,10 +343,12 @@ _schedule_cache: "collections.OrderedDict" = collections.OrderedDict()
 _ell_cache: "collections.OrderedDict" = collections.OrderedDict()
 #: ``inspect_s`` sums the host seconds of every inspection (cache misses
 #: and the serving tier's incremental patches), ``pack_s`` those of every
-#: ELL pack (``_csr_ell`` misses and the op-1 pack)
+#: ELL pack (``_csr_ell`` misses and the op-1 pack); ``spill_lanes`` and
+#: ``spill_virtual_rows`` sum the spill fold over ``_csr_ell`` misses
 _stats = {"hits": 0, "misses": 0, "evictions": 0, "ell_evictions": 0,
           "autotune_sweeps": 0, "incremental_patches": 0,
-          "inspect_s": 0.0, "pack_s": 0.0, "ell_hits": 0, "ell_misses": 0}
+          "inspect_s": 0.0, "pack_s": 0.0, "ell_hits": 0, "ell_misses": 0,
+          "spill_lanes": 0, "spill_virtual_rows": 0}
 _lock = threading.Lock()
 #: The ELL cache has its own lock so its atomic check-and-build (which can
 #: allocate a full-matrix padded ELL) never stalls schedule-cache hits.
@@ -818,9 +823,10 @@ def _autotune_schedule(a: CSR, *, b_col: int, c_col: int,
     return best
 
 
-def _csr_ell(a: CSR, width_cap: int | None = None) -> Tuple[jax.Array, ...]:
+def _csr_ell(a: CSR, width_cap: int | None = None) -> fused_ops.FoldedELL:
     """Memoized full-matrix hybrid ELL (the unfused executor's format),
-    keyed on (content, width cap).
+    keyed on (content, width cap).  A miss counts its spill lanes and
+    virtual rows into ``schedule_cache_stats()``.
 
     Check-and-insert happens under a single ``_ell_lock`` acquisition: the
     previous read-then-write pattern let two threads race past the miss
@@ -844,6 +850,9 @@ def _csr_ell(a: CSR, width_cap: int | None = None) -> Tuple[jax.Array, ...]:
             ell = fused_ops.csr_to_ell(a, width_cap=width_cap)
         _stats["ell_misses"] += 1
         _stats["pack_s"] += time.perf_counter() - t0
+        _stats["spill_lanes"] += int(np.maximum(
+            np.diff(a.indptr) - ell.cols.shape[1], 0).sum())
+        _stats["spill_virtual_rows"] += ell.vrows.shape[0]
         _cache_put(_ell_cache, key, ell, evict_key="ell_evictions")
     return ell
 
@@ -877,9 +886,11 @@ def schedule_cache_stats() -> dict:
     live backward-pass (``transpose=True``) schedules the custom_vjp
     training path inspected — one per (graph, shape) when the transpose
     cache amortizes correctly.  ``inspect_s`` and ``pack_s`` are the host
-    seconds spent inspecting and packing ELLs since the last clear, and
+    seconds spent inspecting and packing ELLs since the last clear,
     ``ell_hits`` / ``ell_misses`` count the full-matrix ELL cache's
-    lookups."""
+    lookups, and ``spill_lanes`` / ``spill_virtual_rows`` (summed over
+    misses) say how much of those ELLs spilled and into how many rows it
+    was folded."""
     with _lock, _ell_lock:
         mesh_entries = layout_1d = layout_15d = layout_25d = 0
         layout_fallback = bucket_entries = transpose_entries = 0
@@ -1099,9 +1110,9 @@ def _dispatch(a: CSR, b_or_a1, c, *, backend: str,
                 hell_a = _csr_ell(a_run, _resolve_width_cap(a_run, width_cap))
                 hell_a1 = _csr_ell(a1_run,
                                    _resolve_width_cap(a1_run, width_cap))
-                return fused_ops.unfused_spmm_spmm(*hell_a, *hell_a1, c)
+                return fused_ops.unfused_spmm_spmm(hell_a, hell_a1, c)
             return fused_ops.unfused_gemm_spmm(
-                *_csr_ell(a_run, _resolve_width_cap(a_run, width_cap)),
+                _csr_ell(a_run, _resolve_width_cap(a_run, width_cap)),
                 jnp.asarray(b_or_a1), c)
 
         if backend == "unfused":
@@ -1180,7 +1191,7 @@ def _transpose_spmm(a: CSR, x: jax.Array, *, transpose: bool,
     content-keyed full-matrix hybrid-ELL cache the unfused executor uses."""
     a_eff = a.transpose() if transpose else a
     return fused_ops.spmm_hybrid(
-        *_csr_ell(a_eff, _resolve_width_cap(a_eff, width_cap)), x)
+        _csr_ell(a_eff, _resolve_width_cap(a_eff, width_cap)), x)
 
 
 def _gemm_spmm_diff(a: CSR, knobs: dict):

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import functools
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -21,10 +22,11 @@ from ..sparse.formats import (CSR, HybridELL, TileELL, csr_content_digest,
 from .schedule import DeviceSchedule
 
 
-def _ell_rows(cols, vals, table):
+def _ell_rows(cols, vals, table, name="ell_body"):
     """rows[j] = Σ_w vals[j, w] · table[cols[j, w]] — scanned over w so the
     gather never materializes the (…, w, c_col) tensor (VMEM/cache friendly,
-    mirrors the kernel's one-hot accumulation loop).
+    mirrors the kernel's one-hot accumulation loop).  The scan runs under
+    the scope ``name``.
 
     Slot 0 seeds the carry instead of a zeros array: the carry then has
     the body's exact type, including the manual mesh axes the operands
@@ -39,7 +41,7 @@ def _ell_rows(cols, vals, table):
     def body(acc, wv):
         return acc + term(*wv), None
 
-    with scope("ell_body"):
+    with scope(name):
         acc, _ = jax.lax.scan(body, term(cols[..., 0], vals[..., 0]),
                               (jnp.moveaxis(cols[..., 1:], -1, 0),
                                jnp.moveaxis(vals[..., 1:], -1, 0)))
@@ -49,7 +51,9 @@ def _ell_rows(cols, vals, table):
 def _spill_add(d, spill_rows, spill_cols, spill_vals, table):
     """Scatter-add COO spill lanes: d[r] += v * table[c] for each lane.
 
-    The hybrid-ELL tail pass: called after the body's ``.set`` scatter so a
+    The tile executors' hybrid-ELL tail pass (their lanes are addressed by
+    tile-padded position; the full-matrix SpMM folds per row instead,
+    ``spmm_hybrid``): called after the body's ``.set`` scatter so a
     capped row's total is body + tail.  Zero lanes are a no-op (traced
     statically — callers may skip the call entirely when size is 0)."""
     with scope("spill"):
@@ -275,18 +279,31 @@ def fused_spmm_spmm(dsched: DeviceSchedule, a1: CSR, c: jax.Array) -> jax.Array:
 # --------------------------------------------------------------------------
 # Unfused baselines (two separate routines, D1 round-trips memory)
 # --------------------------------------------------------------------------
-def csr_to_ell(a: CSR, width_cap: int | None = None):
-    """Full-matrix hybrid ELL (the unfused executor's format).
+class FoldedELL(NamedTuple):
+    """Full-matrix hybrid ELL on the device: the capped body
+    (``cols``/``vals``) and its spill lanes folded per row
+    (``formats.SpillFold``: virtual rows and the row each adds to).
+    Without spill lanes the fold's arrays are empty."""
 
-    Returns the 5-tuple ``(cols, vals, spill_rows, spill_cols, spill_vals)``
-    of device arrays; with ``width_cap=None`` the body is pad-to-max and the
-    spill lanes are empty (the pre-hybrid layout)."""
+    cols: jax.Array
+    vals: jax.Array
+    vcols: jax.Array
+    vvals: jax.Array
+    vrows: jax.Array
+
+
+def csr_to_ell(a: CSR, width_cap: int | None = None) -> FoldedELL:
+    """Full-matrix hybrid ELL (the unfused executor's format) as device
+    arrays; with ``width_cap=None`` the body is pad-to-max and nothing
+    spills (the pre-hybrid layout)."""
     hell = HybridELL.from_csr_rows(
         a, np.arange(a.n_rows),
         cap=width_cap if width_cap is not None else max(a.n_cols, 1))
-    return (jnp.asarray(hell.cols), jnp.asarray(hell.vals, jnp.float32),
-            jnp.asarray(hell.spill_rows), jnp.asarray(hell.spill_cols),
-            jnp.asarray(hell.spill_vals, jnp.float32))
+    fold = hell.spill_fold()
+    return FoldedELL(
+        jnp.asarray(hell.cols), jnp.asarray(hell.vals, jnp.float32),
+        jnp.asarray(fold.vcols), jnp.asarray(fold.vvals, jnp.float32),
+        jnp.asarray(fold.rows))
 
 
 @jax.jit
@@ -295,27 +312,38 @@ def spmm_ell(cols: jax.Array, vals: jax.Array, x: jax.Array) -> jax.Array:
     return _ell_rows(cols, vals.astype(x.dtype), x)
 
 
+def _spill_fold(d, ell: FoldedELL, x):
+    """``d + spill``, one update per virtual row: the virtual rows' SpMM
+    (the body's scan), then a sorted segment sum of its rows into ``d``.
+    Every entry's product is the per-lane pass's; only the order in which
+    a row's terms are added differs."""
+    with scope("spill"):
+        vvals = ell.vvals.astype(x.dtype)
+    part = _ell_rows(ell.vcols, vvals, x, name="spill")
+    with scope("spill"):
+        return d.at[ell.vrows].add(part, indices_are_sorted=True)
+
+
 @jax.jit
-def spmm_hybrid(cols, vals, srows, scols, svals, x):
-    """Hybrid-ELL SpMM: capped body pass + spill-lane scatter-add."""
-    d = _ell_rows(cols, vals.astype(x.dtype), x)
-    if srows.shape[0]:
-        d = _spill_add(d, srows, scols, svals, x)
+def spmm_hybrid(ell: FoldedELL, x):
+    """Hybrid-ELL SpMM: capped body pass, then the spill fold where the
+    pack has spill lanes (with none, the body pass alone)."""
+    d = _ell_rows(ell.cols, ell.vals.astype(x.dtype), x)
+    if ell.vcols.shape[0]:
+        d = _spill_fold(d, ell, x)
     return d
 
 
 @jax.jit
-def unfused_gemm_spmm(cols, vals, srows, scols, svals, b, c):
+def unfused_gemm_spmm(ell: FoldedELL, b, c):
     with scope("gemm"):
         d1 = b @ c
-    return spmm_hybrid(cols, vals, srows, scols, svals, d1)
+    return spmm_hybrid(ell, d1)
 
 
 @jax.jit
-def unfused_spmm_spmm(cols_a, vals_a, srows_a, scols_a, svals_a,
-                      cols_a1, vals_a1, srows_a1, scols_a1, svals_a1, c):
-    d1 = spmm_hybrid(cols_a1, vals_a1, srows_a1, scols_a1, svals_a1, c)
-    return spmm_hybrid(cols_a, vals_a, srows_a, scols_a, svals_a, d1)
+def unfused_spmm_spmm(ell_a: FoldedELL, ell_a1: FoldedELL, c):
+    return spmm_hybrid(ell_a, spmm_hybrid(ell_a1, c))
 
 
 # --------------------------------------------------------------------------

@@ -9,8 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.sparse.formats import CSR
-from repro.core.sparse.random import banded_spd, powerlaw_graph
+from repro.core.sparse.formats import CSR, HybridELL
+from repro.core.sparse.random import banded_spd, hub_powerlaw, powerlaw_graph
 from repro.core.tilefusion import api, fused_ref
 from repro.launch.steps import make_gcn_train_step
 from repro.models.gcn import GCN
@@ -347,6 +347,27 @@ def test_inspect_and_pack_counters_after_a_miss_then_a_hit():
     st = api.schedule_cache_stats()
     assert st["inspect_s"] == st["pack_s"] == 0
     assert st["ell_hits"] == st["ell_misses"] == 0
+
+
+def test_spill_fold_counters_after_a_miss_a_hit_and_a_clear():
+    """``spill_lanes`` / ``spill_virtual_rows`` sum the fold over ELL
+    misses; a hit changes neither."""
+    a = hub_powerlaw(512, seed=0)
+    cap = api._resolve_width_cap(a, "auto")
+    hell = HybridELL.from_csr_rows(a, np.arange(a.n_rows), cap=cap)
+    fold = hell.spill_fold()
+    assert 0 < fold.n_virtual < hell.n_spill
+    b, c = jnp.ones((512, 16)), jnp.ones((16, 8))
+    want = dict(spill_lanes=hell.n_spill, spill_virtual_rows=fold.n_virtual)
+    for misses in (1, 1):                 # a miss, then a hit
+        api.tile_fused_matmul(a, b, c, backend="unfused")
+        st = api.schedule_cache_stats()
+        assert st["ell_misses"] == misses
+        assert {k: st[k] for k in want} == want
+    assert api.schedule_cache_stats()["ell_hits"] == 1
+    api.clear_schedule_cache()
+    st = api.schedule_cache_stats()
+    assert st["spill_lanes"] == st["spill_virtual_rows"] == 0
 
 
 def test_op1_pack_counts_into_pack_s():

@@ -66,7 +66,7 @@ def test_baselines_match_oracle():
     bj, cj = jnp.asarray(b, jnp.float32), jnp.asarray(c, jnp.float32)
     ell = fused_ops.csr_to_ell(a)
     np.testing.assert_allclose(
-        np.asarray(fused_ops.unfused_gemm_spmm(*ell, bj, cj)), want,
+        np.asarray(fused_ops.unfused_gemm_spmm(ell, bj, cj)), want,
         rtol=2e-3, atol=2e-3)
     parts = fused_ops.overlapped_tiles(a, 4)
     np.testing.assert_allclose(
