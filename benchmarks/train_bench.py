@@ -64,7 +64,7 @@ def run():
     model = GCN(cfg, model.adj, cache_size=300_000.0, ct_size=256)
     step = make_gcn_train_step(model, lr=0.1, jit=False)
     steps = 2 if util.smoke() else 10
-    p, _ = step(params, x, y)       # warming step mints the entries once
+    p, _ = step(params, x, y)       # warming step (GeMM-SpMM mints none)
     tr0 = api.schedule_cache_stats()["transpose_entries"]
     t0 = time.perf_counter()
     for _ in range(steps):

@@ -152,20 +152,15 @@ def gcn_inputs(model: GCN, seed: int):
 
 
 def report_backends(model: GCN) -> list:
-    """Print and return what ``auto`` resolves to for each layer, forward
-    and backward (the transpose entries the custom_vjp dispatches)."""
+    """Print and return what ``auto`` resolves to for each layer's forward
+    (the backward always runs one plain transposed SpMM)."""
     picks = []
     for i, e in enumerate(model.entries):
-        et = api.get_schedule(
-            model.adj, b_col=e.c_col, c_col=e.b_col,
-            spec=dataclasses.replace(model.spec, transpose=True,
-                                     dtype_bytes=e.dtype_bytes))
         vmem = {k: round(v / 2**20, 3)
                 for k, v in api.pallas_vmem_bytes(e).items()}
         pick = api.select_backend(e)
         picks.append(pick)
         print(f"  layer {i} {e.b_col}->{e.c_col}: backend={pick} "
-              f"(backward {api.select_backend(et)}) "
               f"fused_ratio={e.sched.fused_ratio:.4f} "
               f"traffic_saving={e.traffic_model['traffic_saving']:.4f} "
               f"t={e.dsched.t_pad} pallas_vmem_MiB={vmem} "
@@ -200,9 +195,9 @@ def grad_parity(model: GCN, params, x, y) -> None:
 
 
 def train_loop(model: GCN, params, x, y) -> None:
-    """``STEPS`` jitted SGD steps through ``auto``: the first compiles (and
-    inspects the backward's transpose schedules), the rest must re-inspect
-    nothing; the loss must stay finite and fall."""
+    """``STEPS`` jitted SGD steps through ``auto``: the first compiles,
+    the rest must re-inspect nothing; the loss must stay finite and
+    fall."""
     step = make_gcn_train_step(model, backend="auto")
     t0 = time.perf_counter()
     params, loss = step(params, x, y)

@@ -322,7 +322,7 @@ class ScheduleEntry:
     #: (``serving.ServingTier``), None for plain content-keyed entries
     bucket: tuple | None = None
     #: True when this entry was inspected on ``a.transpose()`` — the
-    #: backward-pass schedule of the custom_vjp, keyed by the *forward*
+    #: SpMM-SpMM custom_vjp's backward schedule, keyed by the *forward*
     #: digest plus this bit so fwd and bwd entries live side by side
     transpose: bool = False
     #: itemsize of the dense operand the entry prices traffic for; part of
@@ -522,12 +522,12 @@ def get_schedule(a: CSR, *, b_col: int, c_col: int,
     ``bucket`` with ``autotune`` or a non-trivial ``mesh`` raises.
 
     ``spec.transpose=True`` inspects ``a.transpose()`` instead — the
-    backward pass's schedule.  The key stays on the *forward* matrix's
-    digest plus the transpose bit, so the fwd/bwd pair of one training
-    step shares one digest computation and shows up side by side in the
-    cache (``schedule_cache_stats()["transpose_entries"]``).  ``b_col`` /
-    ``c_col`` are the dimensions of the transposed product — the caller
-    passes them already swapped.
+    SpMM-SpMM backward's schedule.  The key stays on the *forward*
+    matrix's digest plus the transpose bit, so the fwd/bwd pair of one
+    training step shares one digest computation and shows up side by
+    side in the cache (``schedule_cache_stats()["transpose_entries"]``).
+    ``b_col`` / ``c_col`` are the dimensions of the transposed product —
+    the caller passes them already swapped.
 
     ``spec.dtype_bytes`` is the dense operand's itemsize; it scales the
     Eq-3 value traffic (index traffic stays at 4 bytes) and joins the
@@ -883,14 +883,14 @@ def schedule_cache_stats() -> dict:
     shape-bucket entries of the serving tier — N patterns mapping to K
     buckets should hold this (and evictions) at K, the LRU-thrash
     regression the serving tests pin.  ``transpose_entries`` counts the
-    live backward-pass (``transpose=True``) schedules the custom_vjp
-    training path inspected — one per (graph, shape) when the transpose
-    cache amortizes correctly.  ``inspect_s`` and ``pack_s`` are the host
-    seconds spent inspecting and packing ELLs since the last clear,
-    ``ell_hits`` / ``ell_misses`` count the full-matrix ELL cache's
-    lookups, and ``spill_lanes`` / ``spill_virtual_rows`` (summed over
-    misses) say how much of those ELLs spilled and into how many rows it
-    was folded."""
+    live backward-pass (``transpose=True``) schedules the SpMM-SpMM
+    custom_vjp inspected — one per (graph, shape) when the transpose
+    cache amortizes correctly; the GeMM-SpMM backward mints none.
+    ``inspect_s`` and ``pack_s`` are the host seconds spent inspecting
+    and packing ELLs since the last clear, ``ell_hits`` / ``ell_misses``
+    count the full-matrix ELL cache's lookups, and ``spill_lanes`` /
+    ``spill_virtual_rows`` (summed over misses) say how much of those
+    ELLs spilled and into how many rows it was folded."""
     with _lock, _ell_lock:
         mesh_entries = layout_1d = layout_15d = layout_25d = 0
         layout_fallback = bucket_entries = transpose_entries = 0
@@ -1096,7 +1096,8 @@ def _dispatch(a: CSR, b_or_a1, c, *, backend: str,
     past the custom_vjp seam.  ``spec.transpose=True`` runs the product
     with all sparse operands transposed (``D = aᵀ·(bᵀ·c)`` structurally —
     for the GeMM-SpMM pair only ``a`` is sparse, so ``D = aᵀ·(b·c)``),
-    serving the backward pass from the transpose-keyed schedule entry."""
+    serving the SpMM-SpMM backward from the transpose-keyed schedule
+    entry."""
     with span("dispatch"):
         b_is_sparse = isinstance(b_or_a1, CSR)
         transpose = spec.transpose
@@ -1172,9 +1173,9 @@ def _dispatch(a: CSR, b_or_a1, c, *, backend: str,
 
 
 def _bwd_knobs(knobs: dict) -> dict:
-    """Knob set for the backward dispatch: the spec flips its transpose
-    bit (so the backward of an already-transposed product runs on the
-    *forward* schedule — (Aᵀ)ᵀ = A), and the serving ``bucket`` — an
+    """Knob set for the SpMM-SpMM backward dispatch: the spec flips its
+    transpose bit (so the backward of an already-transposed product runs
+    on the *forward* schedule — (Aᵀ)ᵀ = A), and the serving ``bucket`` — an
     inference-only shape key — never leaks into training entries.
     Everything else (backend, mesh, tile knobs) carries over so the
     backward lands on the same Eq-3 ``select_backend`` seam."""
@@ -1187,7 +1188,7 @@ def _bwd_knobs(knobs: dict) -> dict:
 def _transpose_spmm(a: CSR, x: jax.Array, *, transpose: bool,
                     width_cap) -> jax.Array:
     """Plain ``Aᵀ·x`` (or ``A·x`` when the forward was transposed) — the
-    second sparse product of the GeMM-SpMM backward, served from the same
+    one sparse product of the GeMM-SpMM backward, served from the same
     content-keyed full-matrix hybrid-ELL cache the unfused executor uses."""
     a_eff = a.transpose() if transpose else a
     return fused_ops.spmm_hybrid(
@@ -1199,13 +1200,12 @@ def _gemm_spmm_diff(a: CSR, knobs: dict):
 
     The CSR and the dispatch knobs are closed over (a frozen dataclass of
     ndarrays can't ride through ``nondiff_argnums``, which wants hashable
-    statics); only the dense operands are traced.  Backward: the two
-    transposed sparse-dense products —
-
-      ``dB = Aᵀ·(Ḋ·Cᵀ)``  (a fused GeMM-SpMM against Aᵀ, dispatched
-      through ``tile_fused_matmul`` with the transpose bit flipped, so it
-      hits the cached transpose schedule and the same backend selection),
-      ``dC = Bᵀ·(Aᵀ·Ḋ)``  (one plain SpMM against Aᵀ, then a dense GeMM).
+    statics); only the dense operands are traced.  Backward: one plain
+    transposed SpMM ``G = Aᵀ·Ḋ`` (at ``c_col``, off the full-matrix
+    hybrid-ELL cache), from which two dense GEMMs derive both cotangents —
+    ``dB = G·Cᵀ`` and ``dC = Bᵀ·G``.  Every backend runs this one path:
+    a fused ``dB = Aᵀ·(Ḋ·Cᵀ)`` would read ``A`` a second time, so sharing
+    ``G`` never adds sparse work, and the backward inspects no schedule.
     """
     def primal(b, c):
         return _dispatch(a, b, c, **knobs)
@@ -1215,13 +1215,13 @@ def _gemm_spmm_diff(a: CSR, knobs: dict):
 
     def bwd(res, dd):
         b, c = res
-        bk = _bwd_knobs(knobs)
+        spec = knobs["spec"]
         with scope("backward"):
-            db = tile_fused_matmul(a, dd, c.T, **bk)
-            g1 = _transpose_spmm(a, dd, transpose=bk["spec"].transpose,
-                                 width_cap=knobs["spec"].width_cap)
+            g = _transpose_spmm(a, dd, transpose=not spec.transpose,
+                                width_cap=spec.width_cap)
             with scope("gemm"):
-                dc = b.T.astype(g1.dtype) @ g1
+                db = g @ c.T.astype(g.dtype)
+                dc = b.T.astype(g.dtype) @ g
         return jnp.asarray(db, b.dtype), jnp.asarray(dc, c.dtype)
 
     f = jax.custom_vjp(primal)
@@ -1294,12 +1294,13 @@ def tile_fused_matmul(a: CSR, b_or_a1, c, *, backend: str = "auto",
 
     **Differentiable.**  When a dense operand is a JAX tracer (i.e. under
     ``jax.grad`` / ``jax.vjp`` / ``jax.jit`` of a differentiated
-    function), the call routes through a ``jax.custom_vjp`` whose
-    backward runs the transposed sparse products on this same fused
-    dispatch — the Pallas/XLA/sharded executors serve the backward too,
-    off schedule entries cached with ``transpose=True`` (inspected once
-    per (content, shape), like the forward).  Eager calls with concrete
-    operands — the serving hot path — skip the vjp machinery entirely.
+    function), the call routes through a ``jax.custom_vjp``.  The
+    GeMM-SpMM backward runs one plain transposed SpMM ``Aᵀ·Ḋ`` and derives
+    ``dB`` and ``dC`` from it with dense GEMMs; the SpMM-SpMM backward
+    runs the transposed product on this same fused dispatch, off schedule
+    entries cached with ``transpose=True`` (inspected once per (content,
+    shape), like the forward).  Eager calls with concrete operands — the
+    serving hot path — skip the vjp machinery entirely.
     """
     spec = _coerce_spec(spec, legacy, "tile_fused_matmul")
     if backend not in BACKENDS:

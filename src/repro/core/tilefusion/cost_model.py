@@ -470,25 +470,23 @@ def spmm_bytes(nnz: int, n_rows: int, n_cols: int, c_col: int,
         + float(nnz) * INDEX_BYTES
 
 
-def train_step_traffic(forward_tm: dict, transpose_tm: dict, *, nnz: int,
-                       n_i: int, n_j: int, c_col: int,
+def train_step_traffic(forward_tm: dict, *, nnz: int, n_i: int, n_j: int,
+                       b_col: int, c_col: int,
                        dtype_bytes: int = 4) -> dict:
     """Per-training-step traffic of the differentiable fused path.
 
-    The backward of ``D = A·(B·C)`` is two sparse-dense products against
-    ``Aᵀ`` (paper §4.2.3 applied to training): the fused
-    ``dB = Aᵀ·(Ḋ·Cᵀ)`` — priced by the *transpose entry's* own Eq-3 model,
-    which was inspected with the swapped (b_col, c_col) — plus the plain
-    ``g1 = Aᵀ·Ḋ`` SpMM feeding ``dC = Bᵀ·g1``.  ``forward_tm`` /
-    ``transpose_tm`` are the two entries' ``traffic_model`` dicts."""
-    g1 = spmm_bytes(nnz, n_i, n_j, c_col, dtype_bytes)
+    The backward of ``D = A·(B·C)`` runs one plain SpMM ``G = Aᵀ·Ḋ`` at
+    ``c_col`` and derives both cotangents from it with dense GEMMs,
+    ``dB = G·Cᵀ`` and ``dC = Bᵀ·G``, each reading its operands once and
+    writing its output once.  ``forward_tm`` is the forward entry's
+    ``traffic_model``; ``A`` is ``n_j × n_i`` with ``nnz`` entries."""
+    spmm = spmm_bytes(nnz, n_i, n_j, c_col, dtype_bytes)
+    gemm = 2.0 * (n_i * c_col + n_i * b_col + b_col * c_col) * dtype_bytes
     fwd = float(forward_tm["fused_bytes"])
-    bwd = float(transpose_tm["fused_bytes"]) + g1
-    bwd_unfused = float(transpose_tm["unfused_bytes"]) + g1
     return {
         "forward_bytes": fwd,
-        "backward_bytes": bwd,
-        "backward_unfused_bytes": bwd_unfused,
-        "train_step_bytes": fwd + bwd,
-        "backward_saving": 1.0 - bwd / max(bwd_unfused, 1.0),
+        "backward_spmm_bytes": spmm,
+        "backward_gemm_bytes": gemm,
+        "backward_bytes": spmm + gemm,
+        "train_step_bytes": fwd + spmm + gemm,
     }

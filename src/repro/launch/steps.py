@@ -44,11 +44,12 @@ def make_gcn_train_step(model, *, lr: float = 0.3, fused: bool = True,
     """SGD train step for a ``models.gcn.GCN`` on the fused path.
 
     The returned ``step(params, x, y) -> (params, loss)`` differentiates
-    through ``tile_fused_matmul``'s custom_vjp, so the backward runs the
-    transposed fused products off the cached transpose schedules — on
-    whatever backend the knobs (or Eq-3 auto selection) resolve to,
-    including under a non-trivial ``mesh=``.  ``jit=False`` returns the
-    eager step (useful for cache-behavior tests)."""
+    through ``tile_fused_matmul``'s custom_vjp, so each layer's backward
+    runs one transposed SpMM off the full-matrix hybrid-ELL cache and two
+    dense GEMMs, whatever backend the forward's knobs (or Eq-3 auto
+    selection) resolve to, including under a non-trivial ``mesh=``.
+    ``jit=False`` returns the eager step (useful for cache-behavior
+    tests)."""
     def step(params, x, y):
         loss, grads = jax.value_and_grad(
             lambda p: model.loss(p, x, y, fused=fused, backend=backend,

@@ -86,22 +86,15 @@ class GCN:
 
     def train_step_traffic_models(self) -> list:
         """Per-layer forward+backward traffic (``cost_model
-        .train_step_traffic``): the transpose entry prices the backward's
-        fused product against Âᵀ, the extra SpMM term its ``Âᵀ·Ḋ``."""
-        import dataclasses
-
+        .train_step_traffic``): the forward entry's Eq-3 model, plus the
+        backward's one ``Âᵀ·Ḋ`` SpMM and the two GEMMs that derive the
+        cotangents from it."""
         from ..core.tilefusion import cost_model
-        out = []
-        for e in self.entries:
-            et = api.get_schedule(
-                self.adj, b_col=e.c_col, c_col=e.b_col,
-                spec=dataclasses.replace(self.spec, transpose=True,
-                                         dtype_bytes=e.dtype_bytes))
-            out.append(cost_model.train_step_traffic(
-                e.traffic_model, et.traffic_model, nnz=self.adj.nnz,
-                n_i=self.adj.n_cols, n_j=self.adj.n_rows, c_col=e.c_col,
-                dtype_bytes=e.dtype_bytes))
-        return out
+        return [cost_model.train_step_traffic(
+                    e.traffic_model, nnz=self.adj.nnz, n_i=self.adj.n_cols,
+                    n_j=self.adj.n_rows, b_col=e.b_col, c_col=e.c_col,
+                    dtype_bytes=e.dtype_bytes)
+                for e in self.entries]
 
     def init_params(self, key):
         cfg = self.cfg

@@ -2,14 +2,15 @@
 
 ``jax.grad`` through ``tile_fused_matmul`` must match the gradient of the
 dense reference product on every backend × op-pair cell: the backward is
-not XLA autodiff through the executors but the api's ``custom_vjp``, whose
-transposed sparse products dispatch back through the same seam (so pallas /
-xla / unfused / sharded all serve the backward, off schedule entries cached
-with ``transpose=True``).  Alongside parity, the suite pins the
-amortization contract — forward+backward of an N-layer GCN costs exactly
-one transpose inspection per (graph, layer shape), with zero re-inspections
-across training steps, eager and jitted — and the dtype-pricing satellite
-(bf16 operands price Eq-3 value traffic at 2 bytes, never 4).
+not XLA autodiff through the executors but the api's ``custom_vjp``.  The
+GeMM-SpMM backward runs one plain transposed SpMM ``G = Aᵀ·Ḋ`` and derives
+``dB = G·Cᵀ`` and ``dC = Bᵀ·G`` with dense GEMMs; the SpMM-SpMM backward
+dispatches its transposed product back through the same seam (off schedule
+entries cached with ``transpose=True``).  Alongside parity, the suite pins
+the amortization contract — forward+backward of an N-layer GCN inspects
+no backward schedule and re-inspects nothing across training steps, eager
+and jitted — and the dtype-pricing satellite (bf16 operands price Eq-3
+value traffic at 2 bytes, never 4).
 """
 import jax
 import jax.numpy as jnp
@@ -98,8 +99,9 @@ def test_grad_parity_cell(op_pair, pattern, dtype_name):
 
 
 def test_backward_served_by_cached_transpose_schedule():
-    """The backward's schedule is a real cache citizen: one grad call mints
-    transpose entries (``transpose_entries`` >= 1), repeat calls hit."""
+    """The GeMM-SpMM backward inspects no schedule: a grad call mints no
+    transpose entry, and repeat calls add no schedule miss and no ELL
+    miss (the transposed ELL is a cache citizen like the forward's)."""
     api.clear_schedule_cache()
     a = banded_spd(64, 4, seed=0)
     b = jnp.ones((64, 8), jnp.float32)
@@ -111,20 +113,19 @@ def test_backward_served_by_cached_transpose_schedule():
 
     jax.grad(loss, argnums=(0, 1))(b, c)
     stats = api.schedule_cache_stats()
-    assert stats["transpose_entries"] >= 1
-    misses = stats["misses"]
+    assert stats["transpose_entries"] == 0
     jax.grad(loss, argnums=(0, 1))(b, c)
     after = api.schedule_cache_stats()
-    assert after["misses"] == misses
-    assert after["transpose_entries"] == stats["transpose_entries"]
+    assert after["misses"] == stats["misses"]
+    assert after["ell_misses"] == stats["ell_misses"]
+    assert after["transpose_entries"] == 0
 
 
 @pytest.mark.parametrize("jit", [False, True])
 def test_gcn_train_one_transpose_inspection_per_shape(jit):
-    """Forward+backward of an N-layer GCN costs exactly one transpose
-    inspection per (graph, layer shape) — the model has two distinct
-    (b_col, c_col) layer shapes, so exactly two transpose entries — and
-    further training steps re-inspect nothing, eager and jitted alike."""
+    """Forward+backward of an N-layer GCN inspects no backward schedule —
+    no transpose entry for any layer shape — and further training steps
+    add no schedule miss and no ELL miss, eager and jitted alike."""
     api.clear_schedule_cache()
     cfg = GCNConfig(n_nodes=96, in_dim=16, hidden_dim=16, out_dim=8,
                     n_layers=3)
@@ -139,15 +140,76 @@ def test_gcn_train_one_transpose_inspection_per_shape(jit):
     step = make_gcn_train_step(model, lr=0.1, jit=jit)
     params, loss0 = step(params, x, y)
     stats = api.schedule_cache_stats()
-    # layer shapes: (16,16) ×2 and (16,8) → two distinct transposed keys
-    assert stats["transpose_entries"] == 2
-    misses = stats["misses"]
+    assert stats["transpose_entries"] == 0
     for _ in range(3):
         params, loss = step(params, x, y)
     after = api.schedule_cache_stats()
-    assert after["misses"] == misses, "training steps re-inspected"
-    assert after["transpose_entries"] == 2
+    assert after["misses"] == stats["misses"], "training steps re-inspected"
+    assert after["ell_misses"] == stats["ell_misses"], "steps repacked"
+    assert after["transpose_entries"] == 0
     assert float(loss) < float(loss0), "SGD on fused grads went uphill"
+
+
+@pytest.mark.parametrize("backend", ["xla", "unfused"])
+def test_gemm_spmm_backward_runs_one_transposed_spmm(backend, monkeypatch):
+    """One GeMM-SpMM grad runs the transposed SpMM exactly once and never
+    re-enters ``tile_fused_matmul``: both cotangents come from the one
+    ``Aᵀ·Ḋ``, and still match the dense reference."""
+    a = hub_powerlaw(64, 4, seed=5)
+    ad = jnp.asarray(a.to_dense(), jnp.float32)
+    rng = np.random.default_rng(3)
+    b = jnp.asarray(rng.standard_normal((64, 8)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((8, 6)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((64, 6)), jnp.float32)
+    fused, spmm = api.tile_fused_matmul, api._transpose_spmm
+    calls = {"tile_fused_matmul": 0, "_transpose_spmm": 0}
+
+    def counted(name, fn):
+        def spy(*args, **kwargs):
+            calls[name] += 1
+            return fn(*args, **kwargs)
+        return spy
+
+    monkeypatch.setattr(api, "tile_fused_matmul",
+                        counted("tile_fused_matmul", fused))
+    monkeypatch.setattr(api, "_transpose_spmm",
+                        counted("_transpose_spmm", spmm))
+    got = jax.grad(lambda b_, c_: jnp.sum(
+        w * fused(a, b_, c_, backend=backend, **KNOBS)),
+        argnums=(0, 1))(b, c)
+    assert calls == {"tile_fused_matmul": 0, "_transpose_spmm": 1}
+    want = jax.grad(lambda b_, c_: jnp.sum(w * (ad @ (b_ @ c_))),
+                    argnums=(0, 1))(b, c)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-3, atol=2e-3)
+
+
+def test_train_step_traffic_prices_one_spmm_and_two_gemms():
+    """The backward's bytes are one ``Aᵀ·Ḋ`` SpMM at ``c_col`` plus the
+    GEMMs ``G·Cᵀ`` and ``Bᵀ·G``, each reading its operands once and
+    writing its output once — and pricing a GCN's step inspects nothing."""
+    tm = cost_model.train_step_traffic(
+        {"fused_bytes": 100.0}, nnz=10, n_i=4, n_j=3, b_col=2, c_col=5)
+    # SpMM: (Ḋ 3×5 in + G 4×5 out + 10 values) × 4 B + 10 indices × 4 B
+    assert tm["backward_spmm_bytes"] == 220.0
+    # GEMMs: 2 × (G 4×5 + B or dB 4×2 + C or dC 2×5) × 4 B
+    assert tm["backward_gemm_bytes"] == 304.0
+    assert tm["backward_bytes"] == 524.0
+    assert tm["train_step_bytes"] == 624.0
+
+    api.clear_schedule_cache()
+    cfg = GCNConfig(n_nodes=64, in_dim=8, hidden_dim=8, out_dim=4,
+                    n_layers=2)
+    model = GCN(cfg, banded_spd(cfg.n_nodes, 4, seed=4), **KNOBS)
+    misses = api.schedule_cache_stats()["misses"]
+    got = model.train_step_traffic_models()
+    assert api.schedule_cache_stats()["misses"] == misses
+    assert api.schedule_cache_stats()["transpose_entries"] == 0
+    for e, t in zip(model.entries, got):
+        assert t["forward_bytes"] == e.traffic_model["fused_bytes"]
+        assert t["backward_spmm_bytes"] == cost_model.spmm_bytes(
+            model.adj.nnz, 64, 64, e.c_col)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64])
