@@ -37,7 +37,7 @@ def main():
                     hidden_dim=args.hidden, out_dim=32, n_layers=2)
     adj = powerlaw_graph(cfg.n_nodes, cfg.avg_degree, seed=0)
     t0 = time.time()
-    model = GCN(cfg, adj, cache_size=300_000.0)
+    model = GCN(cfg, adj, spec=api.FusionSpec(cache_size=300_000.0))
     print(f"schedule inspect: {time.time()-t0:.2f}s (cached for every "
           f"layer/step), fused_ratio={model.sched.fused_ratio:.2f}, "
           f"tiles={len(model.sched.wavefronts[0])}+"
@@ -66,8 +66,7 @@ def main():
         be = "auto" if fused else "unfused"
         picks = ",".join(sorted({api.select_backend(e)
                                  for e in model.entries})) if fused else be
-        step_fn = make_gcn_train_step(model, lr=args.lr, fused=fused,
-                                      backend=be)
+        step_fn = make_gcn_train_step(model, lr=args.lr, backend=be)
         jax.block_until_ready(step_fn(p, x, y))  # compile
         misses0 = api.schedule_cache_stats()["misses"]
         t0 = time.time()
@@ -77,7 +76,8 @@ def main():
         dt = time.time() - t0
         # the printed loss is evaluated at the *post-loop* params — the
         # in-loop value lags one update behind the weights it's reported for
-        final_loss = float(model.loss(p, x, y, fused=fused))
+        final_loss = float(model.loss(
+            p, x, y, backend="xla" if fused else "unfused"))
         stats = api.schedule_cache_stats()
         print(f"{f'fused[{picks}]' if fused else 'unfused'}: "
               f"{args.steps} steps "

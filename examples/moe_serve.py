@@ -1,5 +1,4 @@
-"""Serving example: batched decode on the MoE arch (tile-fusion flagship),
-then a sampled-subgraph stream through the dynamic-pattern serving tier.
+"""Serving example: batched decode on the MoE arch (tile-fusion flagship).
 
   PYTHONPATH=src python examples/moe_serve.py
 """
@@ -9,10 +8,6 @@ from repro.launch import serve
 def main():
     serve.main(["--arch", "granite-moe-3b-a800m", "--reduced",
                 "--batch", "4", "--prompt-len", "16", "--gen", "24"])
-    # dynamic-pattern tier: bucketed schedule reuse + incremental
-    # inspection + batched dispatch over a drifting subgraph stream
-    serve.main(["--subgraphs", "24", "--subgraph-nodes", "192",
-                "--feat-dim", "16", "--out-dim", "8", "--max-batch", "4"])
 
 
 if __name__ == "__main__":

@@ -58,7 +58,7 @@ params = model.init_params(jax.random.PRNGKey(0))
 x = jnp.asarray(rng.standard_normal((cfg.n_nodes, cfg.in_dim)), jnp.float32)
 y = jnp.asarray(rng.integers(0, cfg.out_dim, cfg.n_nodes))
 loss_grad = jax.jit(jax.value_and_grad(
-    lambda p: model.loss(p, x, y, fused=True)))
+    lambda p: model.loss(p, x, y, backend="xla")))
 t0 = time.time()
 for step in range(10):
     loss, grads = loss_grad(params)

@@ -57,8 +57,8 @@ def powerlaw_graph(n: int, avg_deg: int = 8, alpha: float = 2.1, seed: int = 0) 
 
 def hub_powerlaw(n: int, avg_deg: int = 8, seed: int = 0) -> CSR:
     """Power-law graph with one row boosted to degree ~n/2 — the single
-    max-degree hub that makes pad-to-max ELL width explode (the hybrid
-    width-cap stress case shared by benchmarks and regression tests)."""
+    max-degree hub that makes pad-to-max ELL width explode (the tests'
+    hybrid width-cap stress case)."""
     base = powerlaw_graph(n, avg_deg, seed=seed)
     rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(base.indptr))
     hub = int(np.random.default_rng(seed).integers(n))
@@ -143,13 +143,3 @@ SUITES = {
     "block_diag_noise": block_diag_noise,
 }
 
-
-def benchmark_suite(n: int = 4096, seed: int = 0):
-    """The benchmark matrix set: name -> CSR, spanning both paper groups."""
-    return {
-        "banded_spd_b4": banded_spd(n, bandwidth=4, seed=seed),
-        "banded_spd_b16": banded_spd(n, bandwidth=16, seed=seed + 1),
-        "powerlaw_d4": powerlaw_graph(n, avg_deg=4, seed=seed + 2),
-        "powerlaw_d16": powerlaw_graph(n, avg_deg=16, seed=seed + 3),
-        "blockdiag": block_diag_noise(n, block=512, seed=seed + 4),
-    }

@@ -3,9 +3,7 @@
 ``api.tile_fused_matmul`` is the one fused-matmul entrypoint (inspector
 cache + backend dispatch); the submodules below are its building blocks.
 """
-from .cost_model import (DEFAULT_CPU_CACHE_BYTES,
-                         tile_cost_bytes, tile_cost_elements,
-                         tile_costs_batch)
+from .cost_model import tile_cost_bytes, tile_cost_elements, tile_costs_batch
 from .scheduler import (Schedule, Tile, balanced_contiguous_partition,
                         build_schedule, fused_compute_ratio)
 from .schedule import DeviceSchedule, to_device_schedule
@@ -27,5 +25,4 @@ __all__ = [
     "tile_fused_matmul", "get_schedule", "select_backend",
     "clear_schedule_cache", "schedule_cache_stats", "FusionSpec",
     "tile_cost_bytes", "tile_cost_elements", "tile_costs_batch",
-    "DEFAULT_CPU_CACHE_BYTES",
 ]

@@ -17,8 +17,8 @@ owns the two decisions every call site used to repeat by hand:
      both GeMM-SpMM and SpMM-SpMM lower), the XLA vmapped executor, and the
      unfused two-call baseline using the schedule's Eq-3 traffic model:
      patterns that fuse nothing (or would move more bytes fused than
-     unfused) fall back to the unfused code.  Benchmarks pass an explicit
-     ``backend=`` override.
+     unfused) fall back to the unfused code.  An explicit ``backend=``
+     overrides the choice.
 
 **Hybrid-ELL width cap (``width_cap``).**  Every ELL the executors stream
 (wavefront-1 body, SpMM-SpMM op-1, the unfused full-matrix format) is
@@ -32,8 +32,8 @@ scatter-add them lane by lane.  The resolved cap is part
 of the schedule and ELL cache keys, and the autotune sweep tries
 candidate caps alongside tile sizes.
 
-**Tile-size autotuning (``autotune=True``).**  ``get_schedule`` /
-``tile_fused_matmul`` accept ``autotune=True`` to sweep a small
+**Tile-size autotuning (``spec.autotune``).**  ``get_schedule`` /
+``tile_fused_matmul`` take ``FusionSpec(autotune=True)`` to sweep a small
 ``ct_size`` × ``cache_size`` grid (``AUTOTUNE_CT_GRID`` ×
 ``AUTOTUNE_CACHE_SCALES``, plus the caller's own knobs) and keep the
 candidate whose Eq-3 predicted fast-memory traffic, scaled by the
@@ -62,9 +62,8 @@ resolved form (width cap concretized, mesh reduced to ``mesh_key``, inert
 shard knobs collapsed on trivial meshes) is the schedule-cache key tail —
 shared verbatim by the content key, the autotune key, the bucket publish,
 and the custom_vjp backward, so a knob cannot steer dispatch without
-keying the cache.  The historical keyword surface (``p=``, ``ct_size=``,
-``mesh=``, ...) still works as a deprecation shim that builds the spec and
-warns once per process.
+keying the cache.  ``spec=`` is the only way to pass a knob: the seam takes
+no knob keywords.
 
 **Sharded dispatch (``spec.mesh``).**  A non-trivial ``jax.sharding.Mesh``
 partitions the wavefront-0 fused-tile grid row-block over the mesh's row
@@ -95,8 +94,8 @@ trivial mesh falls back to single-device dispatch.  CPU CI exercises the
 real multi-device path via
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.  See ``sharded.py``.
 
-Everything outside ``core/tilefusion`` (models, examples, benchmarks) routes
-through this module; later PRs extend the seam (GPU backend, new layout
+Everything outside ``core/tilefusion`` (models, examples, the benchmark)
+routes through this module; later PRs extend the seam (GPU backend, new layout
 rungs) by adding ``FusionSpec`` fields without touching call sites.
 """
 from __future__ import annotations
@@ -117,8 +116,7 @@ from ..sparse.formats import (CSR, DEFAULT_WIDTH_QUANTILE,
 from . import cost_model, fused_ops, reorder, sharded
 from .schedule import DeviceSchedule, to_device_schedule
 from .scheduler import Schedule, build_schedule
-from .spec import (FusionSpec, reset_legacy_warning,  # noqa: F401 (re-export)
-                   spec_from_legacy_kwargs)
+from .spec import FusionSpec
 
 
 def _shard_for_mesh(a: CSR, sched, dsched, mk: tuple, *, b_col: int,
@@ -207,17 +205,8 @@ def _shard_knobs_key(mk: tuple | None, shard_combine: str,
     return (str(shard_combine), str(shard_layout))
 
 
-def _coerce_spec(spec, legacy: dict, caller: str) -> FusionSpec:
-    """Resolve the ``spec= | **legacy-kwargs`` surface to one FusionSpec.
-
-    Mixing both raises (two sources of truth for one knob is exactly the
-    bug class the spec removes); bare calls get the default spec."""
-    if legacy:
-        if spec is not None:
-            raise TypeError(
-                f"{caller}() got both spec= and legacy keyword(s) "
-                f"{sorted(legacy)}; put every knob on the FusionSpec")
-        return spec_from_legacy_kwargs(legacy, caller=caller)
+def _coerce_spec(spec, caller: str) -> FusionSpec:
+    """``spec=None`` is the default spec; anything but a FusionSpec raises."""
     if spec is None:
         return FusionSpec()
     if not isinstance(spec, FusionSpec):
@@ -464,14 +453,12 @@ def _packed_ell_bytes(a: CSR, dsched: DeviceSchedule, b_is_sparse: bool,
 
 def get_schedule(a: CSR, *, b_col: int, c_col: int,
                  b_is_sparse: bool = False,
-                 spec: FusionSpec | None = None, **legacy) -> ScheduleEntry:
+                 spec: FusionSpec | None = None) -> ScheduleEntry:
     """Run Algorithm 1 once per (content, resolved spec) and memoize;
     subsequent calls with the same key return the cached entry without
     touching the scheduler.
 
-    Every knob lives on the ``FusionSpec`` (``spec=``); the historical
-    keyword surface (``p=``, ``ct_size=``, ``mesh=``, ...) still works as
-    a deprecation shim that builds the spec and warns once per process.
+    Every knob lives on the ``FusionSpec`` (``spec=``).
     ``spec.dtype_bytes=None`` defaults to 4 here — without operands there
     is nothing to infer from (``tile_fused_matmul`` infers before it
     reaches this point).
@@ -547,7 +534,7 @@ def get_schedule(a: CSR, *, b_col: int, c_col: int,
     joins the cache key (``_spec_key``); it does not compose with
     ``bucket``."""
     with span("get_schedule"):
-        spec = _coerce_spec(spec, legacy, "get_schedule")
+        spec = _coerce_spec(spec, "get_schedule")
         if spec.dtype_bytes is None:
             spec = dataclasses.replace(spec, dtype_bytes=4)
         else:
@@ -690,8 +677,7 @@ def _priced_reorder(a_eff: CSR, spec: FusionSpec, *, cap, b_col: int,
 
 def store_bucket_schedule(entry: ScheduleEntry, *, bucket: tuple,
                           patched: bool = False,
-                          spec: FusionSpec | None = None,
-                          **legacy) -> ScheduleEntry:
+                          spec: FusionSpec | None = None) -> ScheduleEntry:
     """Publish a serving-tier entry (headroom-padded at bucket build, or
     patched by the incremental inspector) under its bucket cache key,
     replacing whatever the bucket held.
@@ -705,7 +691,7 @@ def store_bucket_schedule(entry: ScheduleEntry, *, bucket: tuple,
     in ``schedule_cache_stats()``."""
     if entry.content_digest is None:
         raise ValueError("bucket entries need content_digest set")
-    spec = _coerce_spec(spec, legacy, "store_bucket_schedule")
+    spec = _coerce_spec(spec, "store_bucket_schedule")
     spec = dataclasses.replace(
         spec, transpose=False, mesh=None, reorder=None,
         dtype_bytes=4 if spec.dtype_bytes is None else int(spec.dtype_bytes))
@@ -863,9 +849,6 @@ def clear_schedule_cache() -> None:
         _ell_cache.clear()
         for k in _stats:
             _stats[k] = 0
-    # re-arm the once-per-process legacy-kwargs deprecation warning so
-    # warning tests stay order-independent across the suite
-    reset_legacy_warning()
 
 
 def schedule_cache_stats() -> dict:
@@ -1255,7 +1238,7 @@ def _spmm_spmm_diff(a: CSR, a1: CSR, knobs: dict):
 
 
 def tile_fused_matmul(a: CSR, b_or_a1, c, *, backend: str = "auto",
-                      spec: FusionSpec | None = None, **legacy) -> jax.Array:
+                      spec: FusionSpec | None = None) -> jax.Array:
     """``D = a @ (b_or_a1 @ c)`` through the tile-fusion schedule.
 
     Args:
@@ -1264,9 +1247,10 @@ def tile_fused_matmul(a: CSR, b_or_a1, c, *, backend: str = "auto",
         SpMM-SpMM (op-1 rows gathered per tile).
       c: dense ``(b_col, c_col)`` (GeMM-SpMM) / ``(n, c_col)`` (SpMM-SpMM).
       backend: "auto" (Eq-3 cost model + capability), or an explicit
-        "pallas" / "xla" / "unfused" / "sharded" override for benchmarks.
-        Both op pairs lower to "pallas" (SpMM-SpMM via the hybrid op-1
-        gather) and to "sharded" (shard_map over ``spec.mesh``).
+        "pallas" / "xla" / "unfused" / "sharded" override that pins one
+        executor.  Both op pairs lower to "pallas" (SpMM-SpMM via the
+        hybrid op-1 gather) and to "sharded" (shard_map over
+        ``spec.mesh``).
       spec: a ``FusionSpec`` carrying every other knob — Algorithm-1 tile
         parameters (``p``, ``cache_size``, ``ct_size``,
         ``uniform_split``), the ``autotune`` sweep, the hybrid-ELL
@@ -1277,10 +1261,6 @@ def tile_fused_matmul(a: CSR, b_or_a1, c, *, backend: str = "auto",
         ``None`` means the default spec.  See ``spec.FusionSpec`` and
         ``get_schedule`` for per-knob semantics; the resolved spec is the
         schedule-cache key.
-      **legacy: the historical keyword surface (``p=``, ``ct_size=``,
-        ``mesh=``, ...) — a deprecation shim that builds the spec for you
-        and warns once per process.  Mixing ``spec=`` with legacy
-        keywords raises.
 
     Distribution notes: ``spec.mesh`` partitions the wavefront-0 tile
     grid row-block across the mesh's row shards (Eq-3-balanced);
@@ -1302,7 +1282,7 @@ def tile_fused_matmul(a: CSR, b_or_a1, c, *, backend: str = "auto",
     shape), like the forward).  Eager calls with concrete operands — the
     serving hot path — skip the vjp machinery entirely.
     """
-    spec = _coerce_spec(spec, legacy, "tile_fused_matmul")
+    spec = _coerce_spec(spec, "tile_fused_matmul")
     if backend not in BACKENDS:
         raise ValueError(f"backend={backend!r}; expected one of {BACKENDS}")
     c = jnp.asarray(c)

@@ -45,10 +45,6 @@ def operand_dtype_bytes(*operands, default: int = 4) -> int:
                 continue
     return int(default)
 
-#: CPU-style default used by benchmarks mirroring the paper's setting
-#: (L1+L2+L3/core on CascadeLake ~ 2.4 MB).
-DEFAULT_CPU_CACHE_BYTES = int(2.4 * 1024 * 1024)
-
 
 def hybrid_packed_elements(counts: np.ndarray, cap: int | None) -> int:
     """Value slots a HybridELL pack of rows with nonzero ``counts`` streams:

@@ -2,9 +2,7 @@
 
 ``fused_gemm_spmm`` / ``fused_spmm_spmm`` are the jit-compilable fused codes
 (Listing 1 / Listing 3 of the paper, vmapped over tiles instead of OpenMP).
-``unfused_*`` are the two-call baselines.  ``overlapped_*`` (CA-style
-replication) and ``atomic_*`` (sparse-tiling-style multi-wavefront) are the
-prior-work baselines of Figure 6/12, adapted as in paper §4.1.3.
+``unfused_*`` are the two-call baselines.
 """
 from __future__ import annotations
 
@@ -17,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...trace import scope, span
-from ..sparse.formats import (CSR, HybridELL, TileELL, csr_content_digest,
+from ..sparse.formats import (CSR, HybridELL, csr_content_digest,
                               ell_slot_coords)
 from .schedule import DeviceSchedule
 
@@ -345,71 +343,3 @@ def unfused_gemm_spmm(ell: FoldedELL, b, c):
 def unfused_spmm_spmm(ell_a: FoldedELL, ell_a1: FoldedELL, c):
     return spmm_hybrid(ell_a, spmm_hybrid(ell_a1, c))
 
-
-# --------------------------------------------------------------------------
-# Prior-work baselines (paper §4.1.3 adaptations)
-# --------------------------------------------------------------------------
-def overlapped_tiles(a: CSR, p: int):
-    """CA-style overlapped tiling: equal partitions of J; every partition
-    *replicates* all D1 rows its J rows depend on (no synchronization,
-    redundant compute).  Returns per-partition (dep_rows, j_rows)."""
-    parts = np.array_split(np.arange(a.n_rows, dtype=np.int32), p)
-    out = []
-    for jr in parts:
-        if jr.size == 0:
-            continue
-        deps = np.unique(np.concatenate(
-            [a.indices[a.indptr[j]:a.indptr[j + 1]] for j in jr]
-        )) if jr.size else np.zeros(0, np.int32)
-        out.append((deps.astype(np.int32), jr))
-    return out
-
-
-def overlapped_gemm_spmm(a: CSR, parts, b: jax.Array, c: jax.Array) -> jax.Array:
-    """Executes the overlapped schedule; counts replicated GeMV work."""
-    n_j, c_col = a.n_rows, c.shape[1]
-    d = jnp.zeros((n_j, c_col), c.dtype)
-    for deps, jr in parts:
-        ell = TileELL.from_csr_rows(a, jr)
-        # remap global dep columns -> local replicated rows
-        remap = np.zeros(a.n_cols, np.int32)
-        remap[deps] = np.arange(deps.shape[0], dtype=np.int32)
-        loc = remap[ell.cols]
-        d1_rep = b[jnp.asarray(deps)] @ c              # replicated compute
-        rows = jnp.einsum("jw,jwc->jc",
-                          jnp.asarray(ell.vals, c.dtype), d1_rep[jnp.asarray(loc)])
-        d = d.at[jnp.asarray(jr)].set(rows)
-    return d
-
-
-def overlapped_redundancy(a: CSR, p: int) -> float:
-    """Replicated op-1 iterations / |I| (paper's G2_circuit/inline_1 metric)."""
-    parts = overlapped_tiles(a, p)
-    total = sum(int(d.shape[0]) for d, _ in parts)
-    return total / max(a.n_cols, 1)
-
-
-def atomic_tiles(a: CSR, p: int, n_waves: int = 4):
-    """Sparse-tiling-style schedule: J rows partitioned into p*n_waves tiles;
-    each wave is a synchronization barrier (multi-wavefront, vs tile fusion's
-    single barrier).  Models the synchronization overhead, not CPU atomics."""
-    parts = np.array_split(np.arange(a.n_rows, dtype=np.int32), p * n_waves)
-    waves = [parts[w::n_waves] for w in range(n_waves)]
-    return waves
-
-
-def atomic_gemm_spmm(a: CSR, waves, b: jax.Array, c: jax.Array) -> jax.Array:
-    n_j, c_col = a.n_rows, c.shape[1]
-    d1 = b @ c
-    d1.block_until_ready()                     # producer barrier
-    d = jnp.zeros((n_j, c_col), c.dtype)
-    for wave in waves:
-        for jr in wave:
-            if jr.size == 0:
-                continue
-            ell = TileELL.from_csr_rows(a, jr)
-            rows = jnp.einsum("jw,jwc->jc", jnp.asarray(ell.vals, c.dtype),
-                              d1[jnp.asarray(ell.cols)])
-            d = d.at[jnp.asarray(jr)].set(rows)
-        d.block_until_ready()                  # per-wave barrier
-    return d

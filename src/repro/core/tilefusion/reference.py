@@ -2,15 +2,13 @@
 
 The production inspector (``scheduler.py`` / ``schedule.py`` / the ELL
 packers) is O(nnz) vectorized numpy.  This module retains the original
-row-at-a-time implementations verbatim, for two jobs:
+row-at-a-time implementations verbatim as the test oracle: the parity
+property tests (``tests/test_scheduler.py``, ``tests/test_hybrid_ell.py``)
+assert the vectorized scheduler and packers emit *identical* schedules and
+device arrays on random CSR patterns, so the rewrite can never drift
+semantically.
 
-  * the parity property test (``tests/test_scheduler.py``) asserts the
-    vectorized scheduler emits *identical* schedules and device arrays on
-    random CSR patterns, so the rewrite can never drift semantically;
-  * ``benchmarks/inspector_bench.py`` times it as the "before" of the
-    inspector speedup (the §4.2.3 amortization argument needs the number).
-
-Nothing outside tests/benchmarks should import this module.
+Nothing outside the tests should import this module.
 """
 from __future__ import annotations
 

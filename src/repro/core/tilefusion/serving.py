@@ -32,15 +32,15 @@ reusable across *similar* patterns, not just identical ones:
      is O(dirty), full inspection O(nnz).
 
   3. **Cache integration.**  Entries are published under the bucket key
-     (``api.get_schedule(bucket=...)`` / ``api.store_bucket_schedule``):
+     (``api.get_schedule(spec=FusionSpec(bucket=...))`` /
+     ``api.store_bucket_schedule``):
      N patterns in one bucket occupy exactly one cache slot, hits and
      misses stay observable via ``schedule_cache_stats()`` (which also
      counts ``bucket_entries`` and ``incremental_patches``), and the LRU
      bound never thrashes on pattern streams.
 
 The request-batching front end (stacking same-bucket requests into one
-dispatch) lives in ``launch/serve.py::SubgraphFrontEnd``; benchmarks in
-``benchmarks/serving_bench.py``.
+dispatch) lives in ``launch/serve.py::SubgraphFrontEnd``.
 """
 from __future__ import annotations
 

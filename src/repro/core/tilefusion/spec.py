@@ -1,31 +1,19 @@
 """``FusionSpec`` — the one consolidated knob object of the tile-fusion API.
 
-The dispatch seam (``api.get_schedule`` / ``api.tile_fused_matmul``) grew
-twelve keyword knobs, duplicated across four cache-key derivations
-(main key, autotune key, bucket publish, custom_vjp backward).  This
-dataclass is the single source of truth for all of them: callers build one
-frozen ``FusionSpec`` and pass ``spec=``; the spec's *resolved* form
-(width cap concretized, mesh reduced to its hashable key, inert knobs
-canonicalized on trivial meshes) **is** the schedule-cache key tail, so a
-knob can never be part of dispatch without being part of the key.
-
-The legacy keyword surface still works as a deprecation shim:
-``get_schedule(a, ..., p=2, ct_size=32)`` builds a ``FusionSpec`` from the
-kwargs and emits one structured ``DeprecationWarning`` per process (not
-one per call — serving hot loops would drown in them).  New capability
-lands as a spec field (``overlap``, ``n_repl``), not signature growth.
+The dispatch seam (``api.get_schedule`` / ``api.store_bucket_schedule`` /
+``api.tile_fused_matmul``) takes its knobs only as ``spec=``, and the
+same knobs feed four cache-key derivations (main key, autotune key, bucket
+publish, custom_vjp backward).  This dataclass is the single source of
+truth for all of them: callers build one frozen ``FusionSpec``; the spec's
+*resolved* form (width cap concretized, mesh reduced to its hashable key,
+inert knobs canonicalized on trivial meshes) **is** the schedule-cache key
+tail, so a knob can never be part of dispatch without being part of the
+key.  New capability lands as a spec field (``overlap``, ``n_repl``), not
+signature growth.
 """
 from __future__ import annotations
 
 import dataclasses
-import threading
-import warnings
-
-#: Legacy keyword names the shim maps onto spec fields (the historical
-#: twelve plus the knobs added since).  Anything else is a typo and raises.
-LEGACY_KNOBS = ("p", "cache_size", "ct_size", "uniform_split", "autotune",
-                "width_cap", "mesh", "shard_combine", "shard_layout",
-                "bucket", "transpose", "dtype_bytes", "overlap", "n_repl")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,36 +71,3 @@ class FusionSpec:
         if self.bucket is not None:
             object.__setattr__(self, "bucket", tuple(self.bucket))
 
-
-_warned = False
-_warn_lock = threading.Lock()
-
-
-def spec_from_legacy_kwargs(kwargs: dict, *, caller: str) -> FusionSpec:
-    """Deprecation shim: build a ``FusionSpec`` from the historical keyword
-    surface, warning once per process (structured, category
-    ``DeprecationWarning``) with the caller and the knobs that triggered
-    it.  Unknown keywords raise ``TypeError`` exactly like a real
-    signature would."""
-    global _warned
-    unknown = sorted(set(kwargs) - set(LEGACY_KNOBS))
-    if unknown:
-        raise TypeError(f"{caller}() got unexpected keyword argument(s) "
-                        f"{unknown}; knobs live on FusionSpec (spec=)")
-    with _warn_lock:
-        if not _warned:
-            _warned = True
-            warnings.warn(
-                f"{caller}(**{sorted(kwargs)}): passing tile-fusion knobs "
-                f"as keywords is deprecated; build a FusionSpec and pass "
-                f"spec= (this warning is emitted once per process)",
-                DeprecationWarning, stacklevel=3)
-    return FusionSpec(**kwargs)
-
-
-def reset_legacy_warning() -> None:
-    """Re-arm the once-per-process deprecation warning (test hook, called
-    by ``api.clear_schedule_cache`` so warning tests stay order-independent)."""
-    global _warned
-    with _warn_lock:
-        _warned = False

@@ -1,10 +1,9 @@
 """Batched serving driver: prefill + decode loop with KV cache, plus the
-dynamic-pattern subgraph front end over the tile-fusion serving tier.
+request-batching front end (``SubgraphFrontEnd``) of the tile-fusion
+serving tier.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --reduced \\
       --batch 4 --prompt-len 32 --gen 16
-  PYTHONPATH=src python -m repro.launch.serve --subgraphs 24 \\
-      --subgraph-nodes 256 --feat-dim 32 --out-dim 16
 """
 from __future__ import annotations
 
@@ -17,8 +16,6 @@ import numpy as np
 
 from ..configs import get_config
 from ..core.sparse.formats import csr_content_digest
-from ..core.sparse.random import (induced_subgraph, perturb_rows,
-                                  powerlaw_graph)
 from ..core.tilefusion.serving import ServingTier
 from ..models import transformer as T
 from . import steps
@@ -82,46 +79,6 @@ class SubgraphFrontEnd:
         return results
 
 
-def _run_subgraph_stream(args):
-    """Drive a sampled-subgraph request stream through the front end."""
-    rng = np.random.default_rng(args.seed)
-    base = powerlaw_graph(8 * args.subgraph_nodes, avg_deg=6, seed=args.seed)
-    fe = SubgraphFrontEnd(args.feat_dim, args.out_dim, args.max_batch,
-                          p=8, cache_size=600_000.0, ct_size=256)
-    windows = [induced_subgraph(base, s, args.subgraph_nodes)
-               for s in (0, args.subgraph_nodes, 3 * args.subgraph_nodes)]
-    # sampler streams drift: mostly the current minibatch pattern, some
-    # re-sampled neighbor sets, the odd jump to a fresh sample window
-    current = windows[0]
-    t0 = time.time()
-    served = 0
-    while served < args.subgraphs:
-        n_batch = min(args.max_batch, args.subgraphs - served)
-        for _ in range(n_batch):
-            r = rng.random()
-            if r < 0.1 and served:
-                current = windows[int(rng.integers(len(windows)))]
-            elif r < 0.4:
-                k = max(1, current.n_rows // 50)
-                current = perturb_rows(
-                    current, rng.choice(current.n_rows, k, replace=False),
-                    seed=int(rng.integers(1 << 31)))
-            a = current
-            feats = rng.standard_normal((a.n_cols, args.feat_dim))
-            w = rng.standard_normal((args.feat_dim, args.out_dim))
-            fe.submit(a, feats, w)
-            served += 1
-        outs = fe.flush()
-        assert all(o is not None for o in outs)
-    dt = time.time() - t0
-    st = fe.tier.stats
-    print(f"served {served} subgraph requests in {dt:.2f}s "
-          f"({served / max(dt, 1e-9):.1f} req/s) over {fe.batches} batched "
-          f"dispatches")
-    print(f"tier: hit_rate={fe.tier.hit_rate():.2f} exact={st['exact_hits']} "
-          f"incremental={st['incremental']} rebuilds={st['rebuilds']}")
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
@@ -130,18 +87,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--subgraphs", type=int, default=0,
-                    help="serve N sampled-subgraph requests through the "
-                         "tile-fusion serving tier instead of the LM loop")
-    ap.add_argument("--subgraph-nodes", type=int, default=256)
-    ap.add_argument("--feat-dim", type=int, default=32)
-    ap.add_argument("--out-dim", type=int, default=16)
-    ap.add_argument("--max-batch", type=int, default=4)
     args = ap.parse_args(argv)
-
-    if args.subgraphs:
-        _run_subgraph_stream(args)
-        return
 
     cfg = get_config(args.arch, reduced=args.reduced)
     key = jax.random.PRNGKey(args.seed)

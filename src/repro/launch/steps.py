@@ -39,20 +39,20 @@ def make_train_step(cfg, opt_cfg, rules, *, jit: bool = False):
     return jax.jit(train_step) if jit else train_step
 
 
-def make_gcn_train_step(model, *, lr: float = 0.3, fused: bool = True,
-                        backend: str = None, mesh=None, jit: bool = True):
+def make_gcn_train_step(model, *, lr: float = 0.3, backend: str = "auto",
+                        mesh=None, jit: bool = True):
     """SGD train step for a ``models.gcn.GCN`` on the fused path.
 
     The returned ``step(params, x, y) -> (params, loss)`` differentiates
     through ``tile_fused_matmul``'s custom_vjp, so each layer's backward
     runs one transposed SpMM off the full-matrix hybrid-ELL cache and two
-    dense GEMMs, whatever backend the forward's knobs (or Eq-3 auto
-    selection) resolve to, including under a non-trivial ``mesh=``.
+    dense GEMMs, whatever backend ``backend=`` (or Eq-3 auto selection)
+    resolves to, including under a non-trivial ``mesh=``.
     ``jit=False`` returns the eager step (useful for cache-behavior
     tests)."""
     def step(params, x, y):
         loss, grads = jax.value_and_grad(
-            lambda p: model.loss(p, x, y, fused=fused, backend=backend,
+            lambda p: model.loss(p, x, y, backend=backend,
                                  mesh=mesh))(params)
         with scope("sgd.update"):
             return [w - lr * g for w, g in zip(params, grads)], loss

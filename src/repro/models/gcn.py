@@ -8,6 +8,8 @@ every subsequent layer and training step (paper §4.2.3 amortization).
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,18 +49,11 @@ def normalize_adjacency(a: CSR) -> CSR:
 class GCN:
     """Tile-fused GCN on the unified dispatch API."""
 
-    def __init__(self, cfg, adj: CSR, *, p: int = 8,
-                 cache_size: float = 600_000.0, ct_size: int = 2048,
-                 spec: api.FusionSpec | None = None):
+    def __init__(self, cfg, adj: CSR, *, spec: api.FusionSpec | None = None):
         self.cfg = cfg
         self.adj = normalize_adjacency(adj)
-        # one FusionSpec drives every layer's inspection and dispatch; the
-        # scalar ctor knobs survive as sugar for the common case
-        self.spec = spec if spec is not None else api.FusionSpec(
-            p=p, cache_size=cache_size, ct_size=ct_size)
-        self.p = self.spec.p
-        self.cache_size = self.spec.cache_size
-        self.ct_size = self.spec.ct_size
+        # one FusionSpec drives every layer's inspection and dispatch
+        self.spec = spec if spec is not None else api.FusionSpec()
         # warm the inspector cache for every layer shape once per graph;
         # forward() then hits it for every layer and step
         dims = ([cfg.in_dim] + [cfg.hidden_dim] * (cfg.n_layers - 1)
@@ -107,29 +102,23 @@ class GCN:
             for i in range(cfg.n_layers)
         ]
 
-    def forward(self, params, x, *, fused: bool = True, impl: str = None,
-                backend: str = None, mesh=None):
-        """``backend=`` overrides directly; otherwise the legacy
-        (fused, impl) pair maps onto the API's explicit backends.
+    def forward(self, params, x, *, backend: str = "auto", mesh=None):
+        """Every layer dispatches through ``api.tile_fused_matmul`` with
+        ``backend=`` (the seam's own default, "auto", unless overridden).
         Differentiable end to end: under ``jax.grad`` each layer's
-        backward runs the fused transposed products (api custom_vjp),
-        including under a non-trivial ``mesh=``."""
-        import dataclasses
-        be = backend or ("unfused" if not fused
-                         else "pallas" if impl == "pallas" else "xla")
+        backward runs the transposed products (api custom_vjp), including
+        under a non-trivial ``mesh=``."""
         spec = (dataclasses.replace(self.spec, mesh=mesh)
                 if mesh is not None else self.spec)
         for i, w in enumerate(params):
             with scope(f"gcn.layer{i}"):
-                h = api.tile_fused_matmul(self.adj, x, w, backend=be,
+                h = api.tile_fused_matmul(self.adj, x, w, backend=backend,
                                           spec=spec)
                 x = jax.nn.relu(h) if i < len(params) - 1 else h
         return x
 
-    def loss(self, params, x, labels, *, fused: bool = True,
-             backend: str = None, mesh=None):
-        logits = self.forward(params, x, fused=fused, backend=backend,
-                              mesh=mesh)
+    def loss(self, params, x, labels, *, backend: str = "auto", mesh=None):
+        logits = self.forward(params, x, backend=backend, mesh=mesh)
         with scope("gcn.loss"):
             logp = jax.nn.log_softmax(logits, axis=-1)
             return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.sparse.formats import CSR
 from ..core.tilefusion import api, hetero
 from .gcn import normalize_adjacency
 

@@ -1,8 +1,6 @@
 """Unified dispatch API: inspector cache, backend overrides, cost model."""
-import dataclasses
 import re
 import types
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +34,8 @@ def test_cache_hit_identical_pattern_builds_once():
                  a.data.copy())
     assert api.get_schedule(a_copy, b_col=16, c_col=16) is e1
     # a different cache budget is a different schedule
-    api.get_schedule(a, b_col=16, c_col=16, cache_size=5_000.0)
+    api.get_schedule(a, b_col=16, c_col=16,
+                     spec=api.FusionSpec(cache_size=5_000.0))
     assert api.schedule_cache_stats()["misses"] == 2
 
 
@@ -68,7 +67,8 @@ def test_backend_overrides_agree_gemm_spmm():
     cj = jnp.asarray(c, jnp.float32)
     for backend in api.BACKENDS:
         got = api.tile_fused_matmul(a, bj, cj, backend=backend,
-                                    cache_size=50_000.0, ct_size=128)
+                                    spec=api.FusionSpec(cache_size=50_000.0,
+                                                        ct_size=128))
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3,
                                    atol=2e-3, err_msg=backend)
 
@@ -81,7 +81,8 @@ def test_backend_overrides_agree_spmm_spmm():
     cj = jnp.asarray(c, jnp.float32)
     for backend in api.BACKENDS:          # pallas runs interpret off-TPU
         got = api.tile_fused_matmul(a, a, cj, backend=backend,
-                                    cache_size=20_000.0, ct_size=64)
+                                    spec=api.FusionSpec(cache_size=20_000.0,
+                                                        ct_size=64))
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3,
                                    atol=2e-3, err_msg=backend)
 
@@ -93,12 +94,14 @@ def test_select_backend_pallas_spmm_spmm(monkeypatch):
     monkeypatch.setenv("PALLAS_INTERPRET", "1")
     a = banded_spd(256, 4, seed=6)
     entry = api.get_schedule(a, b_col=16, c_col=16, b_is_sparse=True,
-                             cache_size=1e8, ct_size=64)
+                             spec=api.FusionSpec(cache_size=1e8, ct_size=64))
     assert api.select_backend(entry) == "pallas"
     rng = np.random.default_rng(6)
     c = rng.standard_normal((256, 16))
     got = api.tile_fused_matmul(a, a, jnp.asarray(c, jnp.float32),
-                                backend="auto", cache_size=1e8, ct_size=64)
+                                backend="auto",
+                                spec=api.FusionSpec(cache_size=1e8,
+                                                    ct_size=64))
     np.testing.assert_allclose(np.asarray(got),
                                fused_ref.unfused_spmm_spmm(a, a, c),
                                rtol=2e-3, atol=2e-3)
@@ -113,23 +116,27 @@ def test_width_cap_and_autotune_invalidate_cache():
     cache — a capped schedule packs different device arrays, so stale reuse
     would be a silent wrong-layout bug."""
     a = powerlaw_graph(256, 5, seed=7)
-    kw = dict(b_col=8, c_col=8, b_is_sparse=True, cache_size=20_000.0)
-    e_auto = api.get_schedule(a, **kw)                      # auto cap
+    kw = dict(b_col=8, c_col=8, b_is_sparse=True)
+    knobs = dict(cache_size=20_000.0)
+    e_auto = api.get_schedule(a, spec=api.FusionSpec(**knobs), **kw)  # auto
     assert api.schedule_cache_stats()["misses"] == 1
-    e_pad = api.get_schedule(a, width_cap=None, **kw)       # pad-to-max
+    pad = api.FusionSpec(width_cap=None, **knobs)                 # pad-to-max
+    e_pad = api.get_schedule(a, spec=pad, **kw)
     assert e_pad is not e_auto
     assert api.schedule_cache_stats()["misses"] == 2
-    e_int = api.get_schedule(a, width_cap=e_auto.width_cap + 3, **kw)
+    e_int = api.get_schedule(
+        a, spec=api.FusionSpec(width_cap=e_auto.width_cap + 3, **knobs), **kw)
     assert e_int is not e_auto and e_int is not e_pad
     assert api.schedule_cache_stats()["misses"] == 3
     # flipping autotune on is a different entry too (its own sweep key)
-    e_at = api.get_schedule(a, autotune=True, **kw)
+    at = api.FusionSpec(autotune=True, **knobs)
+    e_at = api.get_schedule(a, spec=at, **kw)
     assert e_at is not e_auto
     # every knob repeated verbatim is a pure hit: no rebuild, misses flat
     misses = api.schedule_cache_stats()["misses"]
-    assert api.get_schedule(a, **kw) is e_auto
-    assert api.get_schedule(a, width_cap=None, **kw) is e_pad
-    assert api.get_schedule(a, autotune=True, **kw) is e_at
+    assert api.get_schedule(a, spec=api.FusionSpec(**knobs), **kw) is e_auto
+    assert api.get_schedule(a, spec=pad, **kw) is e_pad
+    assert api.get_schedule(a, spec=at, **kw) is e_at
     assert api.schedule_cache_stats()["misses"] == misses
 
 
@@ -141,9 +148,11 @@ def test_eviction_counters_monotonic(monkeypatch):
     c = jnp.ones((8, 8), jnp.float32)
     last = (0, 0)
     for ct in (16, 32, 64, 128):
-        api.get_schedule(a, b_col=8, c_col=8, ct_size=ct)
+        api.get_schedule(a, b_col=8, c_col=8,
+                         spec=api.FusionSpec(ct_size=ct))
         api.tile_fused_matmul(banded_spd(128, 4, seed=ct), b, c,
-                              backend="unfused", width_cap=ct % 3 or None)
+                              backend="unfused",
+                              spec=api.FusionSpec(width_cap=ct % 3 or None))
         stats = api.schedule_cache_stats()
         cur = (stats["evictions"], stats["ell_evictions"])
         assert cur >= last
@@ -157,14 +166,16 @@ def test_cost_model_falls_back_to_unfused():
     n = 96
     rng = np.random.default_rng(3)
     a = CSR.from_dense(rng.standard_normal((n, n)))
-    entry = api.get_schedule(a, b_col=8, c_col=8, ct_size=16, cache_size=1e12)
+    entry = api.get_schedule(a, b_col=8, c_col=8,
+                             spec=api.FusionSpec(ct_size=16, cache_size=1e12))
     assert entry.sched.fused_ratio < api.MIN_FUSED_RATIO
     assert api.select_backend(entry) == "unfused"
     b = rng.standard_normal((n, 8))
     c = rng.standard_normal((8, 8))
     got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
                                 jnp.asarray(c, jnp.float32), backend="auto",
-                                ct_size=16, cache_size=1e12)
+                                spec=api.FusionSpec(ct_size=16,
+                                                    cache_size=1e12))
     np.testing.assert_allclose(np.asarray(got),
                                fused_ref.unfused_gemm_spmm(a, b, c),
                                rtol=2e-3, atol=2e-3)
@@ -176,7 +187,8 @@ def test_marginal_traffic_saving_falls_back_to_unfused():
     unfused even though the schedule clears the fused-ratio floor (the
     hub-heavy GCN training regime where forced-fused ran ~30% slower)."""
     a = powerlaw_graph(256, 8, seed=11)
-    entry = api.get_schedule(a, b_col=64, c_col=64, cache_size=100_000.0)
+    entry = api.get_schedule(a, b_col=64, c_col=64,
+                             spec=api.FusionSpec(cache_size=100_000.0))
     assert entry.sched.fused_ratio >= api.MIN_FUSED_RATIO
     assert 0.0 < entry.traffic_model["traffic_saving"] \
         <= api.MIN_TRAFFIC_SAVING
@@ -185,8 +197,9 @@ def test_marginal_traffic_saving_falls_back_to_unfused():
 
 def test_auto_selects_fused_on_friendly_pattern():
     a = banded_spd(512, 4, seed=5)
-    entry = api.get_schedule(a, b_col=32, c_col=32, cache_size=100_000.0,
-                             ct_size=128)
+    entry = api.get_schedule(a, b_col=32, c_col=32,
+                             spec=api.FusionSpec(cache_size=100_000.0,
+                                                 ct_size=128))
     assert api.select_backend(entry) in ("xla", "pallas")
 
 
@@ -197,9 +210,11 @@ def test_autotune_never_worse_than_default():
             powerlaw_graph(1024, 4, seed=11)]
     for a in mats:
         api.clear_schedule_cache()
-        e_def = api.get_schedule(a, b_col=32, c_col=32,
-                                 ct_size=api.DEFAULT_CT_SIZE)
-        e_at = api.get_schedule(a, b_col=32, c_col=32, autotune=True)
+        e_def = api.get_schedule(
+            a, b_col=32, c_col=32,
+            spec=api.FusionSpec(ct_size=api.DEFAULT_CT_SIZE))
+        e_at = api.get_schedule(a, b_col=32, c_col=32,
+                                spec=api.FusionSpec(autotune=True))
         assert e_at.traffic_model["fused_bytes"] \
             <= e_def.traffic_model["fused_bytes"]
         assert e_at.autotuned is not None
@@ -208,10 +223,12 @@ def test_autotune_never_worse_than_default():
 
 def test_autotune_sweep_memoized():
     a = banded_spd(512, 4, seed=12)
-    e1 = api.get_schedule(a, b_col=16, c_col=16, autotune=True)
+    e1 = api.get_schedule(a, b_col=16, c_col=16,
+                          spec=api.FusionSpec(autotune=True))
     sweeps = api.schedule_cache_stats()["autotune_sweeps"]
     assert sweeps == 1
-    e2 = api.get_schedule(a, b_col=16, c_col=16, autotune=True)
+    e2 = api.get_schedule(a, b_col=16, c_col=16,
+                          spec=api.FusionSpec(autotune=True))
     assert e2 is e1                       # the sweep ran exactly once
     assert api.schedule_cache_stats()["autotune_sweeps"] == 1
 
@@ -225,7 +242,8 @@ def test_autotune_matmul_matches_reference():
     for backend in ("auto", "xla"):
         got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
                                     jnp.asarray(c, jnp.float32),
-                                    backend=backend, autotune=True)
+                                    backend=backend,
+                                    spec=api.FusionSpec(autotune=True))
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3,
                                    atol=2e-3, err_msg=backend)
 
@@ -234,13 +252,14 @@ def test_cache_lru_eviction(monkeypatch):
     monkeypatch.setenv(api.CACHE_ENTRIES_ENV, "2")
     a = banded_spd(256, 4, seed=0)
     for ct in (32, 64, 128):              # three distinct keys, budget two
-        api.get_schedule(a, b_col=8, c_col=8, ct_size=ct)
+        api.get_schedule(a, b_col=8, c_col=8,
+                         spec=api.FusionSpec(ct_size=ct))
     stats = api.schedule_cache_stats()
     assert stats["entries"] == 2 and stats["evictions"] == 1
     # the evicted (oldest) key re-inspects; the fresh ones still hit
-    api.get_schedule(a, b_col=8, c_col=8, ct_size=128)
+    api.get_schedule(a, b_col=8, c_col=8, spec=api.FusionSpec(ct_size=128))
     assert api.schedule_cache_stats()["hits"] == 1
-    api.get_schedule(a, b_col=8, c_col=8, ct_size=32)
+    api.get_schedule(a, b_col=8, c_col=8, spec=api.FusionSpec(ct_size=32))
     assert api.schedule_cache_stats()["misses"] == 4
 
 
@@ -264,55 +283,35 @@ def test_invalid_backend_rejected():
 
 
 # ---------------------------------------------------------------------------
-# FusionSpec consolidation: spec= is the cache key, legacy kwargs are a shim
+# FusionSpec: spec= is the only knob surface, and the resolved spec is the key
 # ---------------------------------------------------------------------------
 
-def test_spec_and_legacy_kwargs_cut_the_same_cache_key():
-    """Acceptance: a FusionSpec and the equivalent legacy keywords resolve
-    to the SAME schedule-cache entry — the spec really is the key, not a
-    parallel surface that could drift."""
+def test_default_spec_and_spec_none_cut_the_same_cache_key():
+    """``spec=None`` and ``FusionSpec()`` resolve to the SAME schedule-cache
+    entry — the resolved spec really is the key — and a field change is a
+    fresh entry."""
     a = banded_spd(256, 4, seed=20)
-    spec = api.FusionSpec(p=2, cache_size=30_000.0, ct_size=32)
-    e_spec = api.get_schedule(a, b_col=8, c_col=8, spec=spec)
-    assert api.schedule_cache_stats()["misses"] == 1
-    with pytest.warns(DeprecationWarning):
-        e_legacy = api.get_schedule(a, b_col=8, c_col=8, p=2,
-                                    cache_size=30_000.0, ct_size=32)
-    assert e_legacy is e_spec             # pure hit, no rebuild
+    e_none = api.get_schedule(a, b_col=8, c_col=8)
+    e_spec = api.get_schedule(a, b_col=8, c_col=8, spec=api.FusionSpec())
+    assert e_spec is e_none               # pure hit, no rebuild
     stats = api.schedule_cache_stats()
     assert (stats["hits"], stats["misses"]) == (1, 1)
     assert stats["spec_entries"] == 1
-    # a field change is a different resolved spec and a fresh entry
     e2 = api.get_schedule(a, b_col=8, c_col=8,
-                          spec=dataclasses.replace(spec, ct_size=64))
-    assert e2 is not e_spec
+                          spec=api.FusionSpec(ct_size=64))
+    assert e2 is not e_none
     assert api.schedule_cache_stats()["spec_entries"] == 2
 
 
-def test_legacy_kwargs_warn_once_per_process():
-    """The deprecation shim is structured (DeprecationWarning) and fires
-    exactly once per process; clear_schedule_cache re-arms it so tests
-    stay order-independent."""
-    a = banded_spd(128, 4, seed=21)
-    with pytest.warns(DeprecationWarning, match="FusionSpec"):
-        api.get_schedule(a, b_col=8, c_col=8, p=2)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        api.get_schedule(a, b_col=8, c_col=8, p=4)   # second call: silent
-    assert not [w for w in caught
-                if issubclass(w.category, DeprecationWarning)]
-    api.clear_schedule_cache()                       # re-arms the warning
-    with pytest.warns(DeprecationWarning):
-        api.get_schedule(a, b_col=8, c_col=8, p=2)
-
-
-def test_mixing_spec_and_legacy_kwargs_rejected():
+def test_knob_keywords_and_non_spec_rejected():
+    """Knobs ride only on ``spec=``: a knob keyword is an unknown keyword,
+    and ``spec=`` takes nothing but a FusionSpec."""
     a = banded_spd(64, 2, seed=22)
-    with pytest.raises(TypeError, match="both spec="):
-        api.get_schedule(a, b_col=4, c_col=4,
-                         spec=api.FusionSpec(), ct_size=32)
     with pytest.raises(TypeError, match="unexpected keyword"):
-        api.get_schedule(a, b_col=4, c_col=4, ct_sizee=32)  # typo knob
+        api.get_schedule(a, b_col=4, c_col=4, ct_size=32)
+    with pytest.raises(TypeError, match="unexpected keyword"):
+        api.tile_fused_matmul(a, jnp.ones((64, 4)), jnp.ones((4, 4)),
+                              ct_size=32)
     with pytest.raises(TypeError, match="FusionSpec"):
         api.get_schedule(a, b_col=4, c_col=4, spec={"p": 2})
 
@@ -395,7 +394,7 @@ def test_scopes_name_every_layer_of_the_compiled_gcn_step(backend, graph,
            else banded_spd(n, 4, seed=1))
     cfg = types.SimpleNamespace(n_nodes=n, in_dim=16, hidden_dim=32,
                                 out_dim=8, n_layers=2)
-    model = GCN(cfg, adj, ct_size=128)
+    model = GCN(cfg, adj, spec=api.FusionSpec(ct_size=128))
     step = make_gcn_train_step(model, lr=0.1, backend=backend)
     params = model.init_params(jax.random.PRNGKey(0))
     text = step.lower(params, jnp.ones((n, 16)),

@@ -68,14 +68,6 @@ def test_baselines_match_oracle():
     np.testing.assert_allclose(
         np.asarray(fused_ops.unfused_gemm_spmm(ell, bj, cj)), want,
         rtol=2e-3, atol=2e-3)
-    parts = fused_ops.overlapped_tiles(a, 4)
-    np.testing.assert_allclose(
-        np.asarray(fused_ops.overlapped_gemm_spmm(a, parts, bj, cj)), want,
-        rtol=2e-3, atol=2e-3)
-    waves = fused_ops.atomic_tiles(a, 4)
-    np.testing.assert_allclose(
-        np.asarray(fused_ops.atomic_gemm_spmm(a, waves, bj, cj)), want,
-        rtol=2e-3, atol=2e-3)
 
 
 def _device_schedule_with_tiles(i_starts, i_lens, t_pad):
@@ -110,9 +102,3 @@ def test_is_uniform_grid_and_non_grid():
     assert not fused_ops._is_uniform(
         _device_schedule_with_tiles([0, 8, 16], [8, 4, 8], 8))
 
-
-def test_overlapped_redundancy_positive():
-    """CA-style tiling replicates work (the paper's critique)."""
-    a = powerlaw_graph(512, 8, seed=2)
-    red = fused_ops.overlapped_redundancy(a, 8)
-    assert red > 1.0  # deps replicated across partitions

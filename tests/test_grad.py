@@ -68,7 +68,7 @@ def _grad_cell(a: CSR, op_pair: str, backend: str, dtype) -> tuple:
         w = jnp.asarray(rng.standard_normal((n, 6)), dtype)
         got = jax.grad(lambda c_: jnp.sum(
             w * api.tile_fused_matmul(a, a, c_, backend=backend,
-                                      **kwargs)))(c)
+                                      spec=api.FusionSpec(**kwargs))))(c)
         want = jax.grad(lambda c_: jnp.sum(w * (ad @ (ad @ c_))))(c)
         return (np.asarray(got, np.float32),), (np.asarray(want,
                                                            np.float32),)
@@ -76,7 +76,8 @@ def _grad_cell(a: CSR, op_pair: str, backend: str, dtype) -> tuple:
     c = jnp.asarray(rng.standard_normal((8, 6)), dtype)
     w = jnp.asarray(rng.standard_normal((n, 6)), dtype)
     got = jax.grad(lambda b_, c_: jnp.sum(
-        w * api.tile_fused_matmul(a, b_, c_, backend=backend, **kwargs)),
+        w * api.tile_fused_matmul(a, b_, c_, backend=backend,
+                                  spec=api.FusionSpec(**kwargs))),
         argnums=(0, 1))(b, c)
     want = jax.grad(lambda b_, c_: jnp.sum(w * (ad @ (b_ @ c_))),
                     argnums=(0, 1))(b, c)
@@ -108,8 +109,8 @@ def test_backward_served_by_cached_transpose_schedule():
     c = jnp.ones((8, 4), jnp.float32)
 
     def loss(b_, c_):
-        return jnp.sum(api.tile_fused_matmul(a, b_, c_, backend="xla",
-                                             **KNOBS) ** 2)
+        return jnp.sum(api.tile_fused_matmul(
+            a, b_, c_, backend="xla", spec=api.FusionSpec(**KNOBS)) ** 2)
 
     jax.grad(loss, argnums=(0, 1))(b, c)
     stats = api.schedule_cache_stats()
@@ -130,14 +131,14 @@ def test_gcn_train_one_transpose_inspection_per_shape(jit):
     cfg = GCNConfig(n_nodes=96, in_dim=16, hidden_dim=16, out_dim=8,
                     n_layers=3)
     adj = banded_spd(cfg.n_nodes, 4, seed=1)
-    model = GCN(cfg, adj, **{k: v for k, v in KNOBS.items()})
+    model = GCN(cfg, adj, spec=api.FusionSpec(**KNOBS))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((cfg.n_nodes, cfg.in_dim)),
                     jnp.float32)
     y = jnp.asarray(rng.integers(0, cfg.out_dim, cfg.n_nodes))
     params = model.init_params(jax.random.PRNGKey(0))
 
-    step = make_gcn_train_step(model, lr=0.1, jit=jit)
+    step = make_gcn_train_step(model, lr=0.1, backend="xla", jit=jit)
     params, loss0 = step(params, x, y)
     stats = api.schedule_cache_stats()
     assert stats["transpose_entries"] == 0
@@ -175,7 +176,7 @@ def test_gemm_spmm_backward_runs_one_transposed_spmm(backend, monkeypatch):
     monkeypatch.setattr(api, "_transpose_spmm",
                         counted("_transpose_spmm", spmm))
     got = jax.grad(lambda b_, c_: jnp.sum(
-        w * fused(a, b_, c_, backend=backend, **KNOBS)),
+        w * fused(a, b_, c_, backend=backend, spec=api.FusionSpec(**KNOBS))),
         argnums=(0, 1))(b, c)
     assert calls == {"tile_fused_matmul": 0, "_transpose_spmm": 1}
     want = jax.grad(lambda b_, c_: jnp.sum(w * (ad @ (b_ @ c_))),
@@ -201,7 +202,8 @@ def test_train_step_traffic_prices_one_spmm_and_two_gemms():
     api.clear_schedule_cache()
     cfg = GCNConfig(n_nodes=64, in_dim=8, hidden_dim=8, out_dim=4,
                     n_layers=2)
-    model = GCN(cfg, banded_spd(cfg.n_nodes, 4, seed=4), **KNOBS)
+    model = GCN(cfg, banded_spd(cfg.n_nodes, 4, seed=4),
+                spec=api.FusionSpec(**KNOBS))
     misses = api.schedule_cache_stats()["misses"]
     got = model.train_step_traffic_models()
     assert api.schedule_cache_stats()["misses"] == misses
@@ -247,8 +249,10 @@ def test_dtype_pricing_splits_value_and_index_traffic():
     half the f32 one (all-value) and the f32 one (all-index)."""
     api.clear_schedule_cache()
     a = banded_spd(96, 4, seed=2)
-    e32 = api.get_schedule(a, b_col=8, c_col=8, dtype_bytes=4, **KNOBS)
-    e16 = api.get_schedule(a, b_col=8, c_col=8, dtype_bytes=2, **KNOBS)
+    e32 = api.get_schedule(a, b_col=8, c_col=8,
+                           spec=api.FusionSpec(dtype_bytes=4, **KNOBS))
+    e16 = api.get_schedule(a, b_col=8, c_col=8,
+                           spec=api.FusionSpec(dtype_bytes=2, **KNOBS))
     f32b, f16b = (e32.traffic_model["fused_bytes"],
                   e16.traffic_model["fused_bytes"])
     assert 0.5 * f32b < f16b < f32b
@@ -260,7 +264,7 @@ def test_dtype_pricing_splits_value_and_index_traffic():
     misses = api.schedule_cache_stats()["misses"]
     api.tile_fused_matmul(a, jnp.ones((96, 8), jnp.bfloat16),
                           jnp.ones((8, 8), jnp.bfloat16), backend="xla",
-                          **KNOBS)
+                          spec=api.FusionSpec(**KNOBS))
     assert api.schedule_cache_stats()["misses"] == misses
 
 
@@ -272,7 +276,7 @@ def test_grad_under_mesh_trains():
     cfg = GCNConfig(n_nodes=64, in_dim=8, hidden_dim=8, out_dim=4,
                     n_layers=2)
     adj = banded_spd(cfg.n_nodes, 4, seed=3)
-    model = GCN(cfg, adj, **{k: v for k, v in KNOBS.items()})
+    model = GCN(cfg, adj, spec=api.FusionSpec(**KNOBS))
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((cfg.n_nodes, cfg.in_dim)),
                     jnp.float32)

@@ -15,7 +15,6 @@ import pytest
 
 from repro.core.sparse.formats import (CSR, block_diag_csr,
                                        csr_content_digest)
-from repro.core.sparse.random import powerlaw_graph
 from repro.core.tilefusion import api, hetero
 
 SPEC = api.FusionSpec(p=2, cache_size=30_000.0, ct_size=32)

@@ -86,7 +86,8 @@ def _run_cell(a: CSR, op_pair: str, backend: str, c_col: int,
     if backend == "reference":
         entry = api.get_schedule(a, b_col=c_col if op_pair == "spmm" else 8,
                                  c_col=c_col,
-                                 b_is_sparse=(op_pair == "spmm"), **KNOBS)
+                                 b_is_sparse=(op_pair == "spmm"),
+                                 spec=api.FusionSpec(**KNOBS))
         if op_pair == "spmm":
             got = fused_ref.run_spmm_spmm(a, a, c_sp, entry.sched, check=True)
             want = fused_ref.unfused_spmm_spmm(a, a, c_sp)
@@ -123,12 +124,14 @@ def _run_cell(a: CSR, op_pair: str, backend: str, c_col: int,
         backend = "sharded"
     if op_pair == "spmm":
         got = api.tile_fused_matmul(a, a, jnp.asarray(c_sp, jnp.float32),
-                                    backend=backend, **kwargs)
+                                    backend=backend,
+                                    spec=api.FusionSpec(**kwargs))
         want = fused_ref.unfused_spmm_spmm(a, a, c_sp)
     else:
         got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
                                     jnp.asarray(c_ge, jnp.float32),
-                                    backend=backend, **kwargs)
+                                    backend=backend,
+                                    spec=api.FusionSpec(**kwargs))
         want = fused_ref.unfused_gemm_spmm(a, b, c_ge)
     return np.asarray(got), want
 
@@ -158,19 +161,18 @@ def test_reduce_scatter_combine_matches_psum(op_pair):
     outs = {}
     for combine in ("psum", "reduce_scatter"):
         mesh = _host_mesh()
-        kwargs = dict(KNOBS, mesh=mesh, backend="sharded",
-                      shard_combine=combine)
+        spec = api.FusionSpec(mesh=mesh, shard_combine=combine, **KNOBS)
         if op_pair == "spmm":
             c = rng.standard_normal((96, 8))
             got = api.tile_fused_matmul(a, a, jnp.asarray(c, jnp.float32),
-                                        **kwargs)
+                                        backend="sharded", spec=spec)
             want = fused_ref.unfused_spmm_spmm(a, a, c)
         else:
             b = rng.standard_normal((96, 8))
             c = rng.standard_normal((8, 8))
             got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
                                         jnp.asarray(c, jnp.float32),
-                                        **kwargs)
+                                        backend="sharded", spec=spec)
             want = fused_ref.unfused_gemm_spmm(a, b, c)
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3,
                                    atol=2e-3, err_msg=combine)
@@ -178,7 +180,7 @@ def test_reduce_scatter_combine_matches_psum(op_pair):
         if len(jax.devices()) > 1:
             entry = api.get_schedule(
                 a, b_col=8, c_col=8, b_is_sparse=(op_pair == "spmm"),
-                mesh=mesh, shard_combine=combine, **KNOBS)
+                spec=spec)
             assert entry.shard is not None
             assert entry.shard.combine == combine
         rng = np.random.default_rng(1)     # same operands for both modes
@@ -192,7 +194,8 @@ def test_hub_row_spills_under_auto_cap():
     pack) must carry spill entries — and parity above proves they land."""
     a = hub_powerlaw(96, 4, seed=0)
     api.clear_schedule_cache()
-    entry = api.get_schedule(a, b_col=8, c_col=8, b_is_sparse=True, **KNOBS)
+    entry = api.get_schedule(a, b_col=8, c_col=8, b_is_sparse=True,
+                             spec=api.FusionSpec(**KNOBS))
     counts = np.diff(a.indptr)
     assert entry.width_cap is not None
     assert entry.width_cap < int(counts.max())

@@ -114,8 +114,8 @@ def test_serving_bucket_price_tradeoff():
 
 def test_pad_device_schedule_noop_and_shapes():
     a = _graph(100)
-    entry = api.get_schedule(a, b_col=8, c_col=8, uniform_split=True,
-                             **KNOBS)
+    entry = api.get_schedule(a, b_col=8, c_col=8,
+                             spec=api.FusionSpec(uniform_split=True, **KNOBS))
     ds = entry.dsched
     assert pad_device_schedule(ds) is ds
     ds2 = pad_device_schedule(ds, j1_slots=10, spill_slots=40)
@@ -138,7 +138,7 @@ def test_pad_device_schedule_noop_and_shapes():
 def _padded_entry(a, *, b_is_sparse=False, slack=16):
     import dataclasses
     entry = api.get_schedule(a, b_col=8, c_col=8, b_is_sparse=b_is_sparse,
-                             uniform_split=True, **KNOBS)
+                             spec=api.FusionSpec(uniform_split=True, **KNOBS))
     ds = pad_device_schedule(entry.dsched, j1_slots=slack,
                              spill_slots=slack * 8)
     return dataclasses.replace(entry, dsched=ds)
@@ -322,5 +322,6 @@ def test_front_end_batched_matches_per_request():
 def test_bucket_knob_rejects_bad_compositions():
     a = _graph(100)
     with pytest.raises(ValueError):
-        api.get_schedule(a, b_col=8, c_col=8, bucket=(128, 128, None),
-                         autotune=True, **KNOBS)
+        api.get_schedule(a, b_col=8, c_col=8,
+                         spec=api.FusionSpec(bucket=(128, 128, None),
+                                             autotune=True, **KNOBS))

@@ -168,7 +168,7 @@ def test_choose_mesh_layout_prefers_replication_when_halo_dominates():
 # --------------------------------------------------------------------------
 def test_sharded_schedule_structure():
     a = powerlaw_graph(256, 5, seed=3)
-    entry = api.get_schedule(a, b_col=8, c_col=8, **KNOBS)
+    entry = api.get_schedule(a, b_col=8, c_col=8, spec=api.FusionSpec(**KNOBS))
     shard = sharded.build_sharded_schedule(
         a, entry.sched, entry.dsched, 4, b_col=8, c_col=8,
         b_is_sparse=False, width_cap=entry.width_cap)
@@ -197,8 +197,10 @@ def test_sharded_schedule_structure():
 
 def test_sharded_schedule_requires_uniform_grid():
     a = powerlaw_graph(128, 4, seed=1)
-    entry = api.get_schedule(a, b_col=8, c_col=8, uniform_split=False,
-                             p=2, cache_size=2_000.0, ct_size=32)
+    entry = api.get_schedule(a, b_col=8, c_col=8,
+                             spec=api.FusionSpec(uniform_split=False, p=2,
+                                                 cache_size=2_000.0,
+                                                 ct_size=32))
     if not api.fused_ops._is_uniform(entry.dsched):
         assert sharded.build_sharded_schedule(
             a, entry.sched, entry.dsched, 4, b_col=8, c_col=8,
@@ -210,32 +212,37 @@ def test_sharded_schedule_requires_uniform_grid():
 # --------------------------------------------------------------------------
 def test_mesh_shape_misses_schedule_cache():
     a = banded_spd(128, 4, seed=0)
-    e_plain = api.get_schedule(a, b_col=8, c_col=8, **KNOBS)
+    e_plain = api.get_schedule(a, b_col=8, c_col=8,
+                               spec=api.FusionSpec(**KNOBS))
     assert api.schedule_cache_stats()["misses"] == 1
     assert api.schedule_cache_stats()["mesh_entries"] == 0
 
     mesh1 = _mesh(1)
     # a trivial mesh keys exactly like no mesh: pure hit, not a new entry
-    assert api.get_schedule(a, b_col=8, c_col=8, mesh=mesh1,
-                            **KNOBS) is e_plain
+    assert api.get_schedule(
+        a, b_col=8, c_col=8,
+        spec=api.FusionSpec(mesh=mesh1, **KNOBS)) is e_plain
     assert api.schedule_cache_stats()["misses"] == 1
 
     if len(jax.devices()) < 2:
         pytest.skip("needs >1 device for non-trivial mesh keys "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
     mesh_n = _mesh()
-    e_mesh = api.get_schedule(a, b_col=8, c_col=8, mesh=mesh_n, **KNOBS)
+    e_mesh = api.get_schedule(a, b_col=8, c_col=8,
+                              spec=api.FusionSpec(mesh=mesh_n, **KNOBS))
     assert e_mesh is not e_plain            # same content, new mesh: miss
     assert e_mesh.shard is not None
     stats = api.schedule_cache_stats()
     assert stats["misses"] == 2 and stats["mesh_entries"] == 1
     # same mesh shape under a different Mesh object: hit
-    assert api.get_schedule(a, b_col=8, c_col=8, mesh=_mesh(),
-                            **KNOBS) is e_mesh
+    assert api.get_schedule(
+        a, b_col=8, c_col=8,
+        spec=api.FusionSpec(mesh=_mesh(), **KNOBS)) is e_mesh
     # a different mesh *shape* over the same devices: miss again
     devs = jax.devices()
     mesh_2d = Mesh(np.array(devs).reshape(2, -1), ("x", "y"))
-    e_2d = api.get_schedule(a, b_col=8, c_col=8, mesh=mesh_2d, **KNOBS)
+    e_2d = api.get_schedule(a, b_col=8, c_col=8,
+                            spec=api.FusionSpec(mesh=mesh_2d, **KNOBS))
     assert e_2d is not e_mesh
     stats = api.schedule_cache_stats()
     assert stats["misses"] == 3 and stats["mesh_entries"] == 2
@@ -247,12 +254,13 @@ def test_trivial_mesh_falls_back_single_device():
     b = rng.standard_normal((64, 8))
     c = rng.standard_normal((8, 8))
     got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
-                                jnp.asarray(c, jnp.float32),
-                                backend="sharded", mesh=_mesh(1), **KNOBS)
+                                jnp.asarray(c, jnp.float32), backend="sharded",
+                                spec=api.FusionSpec(mesh=_mesh(1), **KNOBS))
     np.testing.assert_allclose(np.asarray(got),
                                fused_ref.unfused_gemm_spmm(a, b, c),
                                rtol=2e-3, atol=2e-3)
-    entry = api.get_schedule(a, b_col=8, c_col=8, mesh=_mesh(1), **KNOBS)
+    entry = api.get_schedule(a, b_col=8, c_col=8,
+                             spec=api.FusionSpec(mesh=_mesh(1), **KNOBS))
     assert entry.shard is None and entry.mesh_key is None
 
 
@@ -270,19 +278,21 @@ def test_sharded_parity_multi_device(op_pair):
     if op_pair == "spmm":
         c = rng.standard_normal((96, 8))
         got = api.tile_fused_matmul(a, a, jnp.asarray(c, jnp.float32),
-                                    backend="sharded", mesh=mesh, **KNOBS)
+                                    backend="sharded",
+                                    spec=api.FusionSpec(mesh=mesh, **KNOBS))
         want = fused_ref.unfused_spmm_spmm(a, a, c)
     else:
         b = rng.standard_normal((96, 8))
         c = rng.standard_normal((8, 8))
         got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
                                     jnp.asarray(c, jnp.float32),
-                                    backend="sharded", mesh=mesh, **KNOBS)
+                                    backend="sharded",
+                                    spec=api.FusionSpec(mesh=mesh, **KNOBS))
         want = fused_ref.unfused_gemm_spmm(a, b, c)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3, atol=2e-3)
     entry = api.get_schedule(a, b_col=8, c_col=8,
-                             b_is_sparse=(op_pair == "spmm"), mesh=mesh,
-                             **KNOBS)
+                             b_is_sparse=(op_pair == "spmm"),
+                             spec=api.FusionSpec(mesh=mesh, **KNOBS))
     assert entry.shard is not None
     assert api.select_backend(entry) == "sharded"
     assert entry.traffic_model["sharded"]["halo_rows"] \
@@ -298,15 +308,16 @@ def test_auto_with_mesh_dispatches_sharded_even_unfusable():
     from repro.core.sparse.formats import CSR
     rng = np.random.default_rng(4)
     a = CSR.from_dense(rng.standard_normal((64, 64)))   # dense: fuses nothing
-    entry = api.get_schedule(a, b_col=8, c_col=8, mesh=_mesh(), **KNOBS)
+    entry = api.get_schedule(a, b_col=8, c_col=8,
+                             spec=api.FusionSpec(mesh=_mesh(), **KNOBS))
     assert entry.sched.fused_ratio < api.MIN_FUSED_RATIO
     assert entry.shard is not None
     assert api.select_backend(entry) == "sharded"
     b = rng.standard_normal((64, 8))
     c = rng.standard_normal((8, 8))
     got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
-                                jnp.asarray(c, jnp.float32),
-                                backend="auto", mesh=_mesh(), **KNOBS)
+                                jnp.asarray(c, jnp.float32), backend="auto",
+                                spec=api.FusionSpec(mesh=_mesh(), **KNOBS))
     np.testing.assert_allclose(np.asarray(got),
                                fused_ref.unfused_gemm_spmm(a, b, c),
                                rtol=2e-3, atol=2e-3)
@@ -346,20 +357,21 @@ from repro.core.tilefusion import api, fused_ref
 a = hub_powerlaw(96, 4, seed=0)
 rng = np.random.default_rng(0)
 knobs = dict(p=2, cache_size=30_000.0, ct_size=32)
+spec1d = api.FusionSpec(mesh=mesh, **knobs)
 b = rng.standard_normal((96, 8)); cg = rng.standard_normal((8, 8))
 got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
                             jnp.asarray(cg, jnp.float32),
-                            backend="sharded", mesh=mesh, **knobs)
+                            backend="sharded", spec=spec1d)
 np.testing.assert_allclose(np.asarray(got),
                            fused_ref.unfused_gemm_spmm(a, b, cg),
                            rtol=2e-3, atol=2e-3)
 cs = rng.standard_normal((96, 8))
 got = api.tile_fused_matmul(a, a, jnp.asarray(cs, jnp.float32),
-                            backend="sharded", mesh=mesh, **knobs)
+                            backend="sharded", spec=spec1d)
 np.testing.assert_allclose(np.asarray(got),
                            fused_ref.unfused_spmm_spmm(a, a, cs),
                            rtol=2e-3, atol=2e-3)
-entry = api.get_schedule(a, b_col=8, c_col=8, mesh=mesh, **knobs)
+entry = api.get_schedule(a, b_col=8, c_col=8, spec=spec1d)
 assert entry.shard.n_shards == 8
 
 # 2) 2-D mesh cells: both layouts x both combines on a real 4x2 partition
@@ -370,17 +382,19 @@ for layout in ("1d", "1.5d"):
     for combine in ("psum", "reduce_scatter"):
         got = api.tile_fused_matmul(a, jnp.asarray(b, jnp.float32),
                                     jnp.asarray(cg, jnp.float32),
-                                    backend="sharded", mesh=mesh2d,
-                                    shard_layout=layout,
-                                    shard_combine=combine, **knobs)
+                                    backend="sharded",
+                                    spec=api.FusionSpec(
+                                        mesh=mesh2d, shard_layout=layout,
+                                        shard_combine=combine, **knobs))
         np.testing.assert_allclose(np.asarray(got), want_g,
                                    rtol=2e-3, atol=2e-3,
                                    err_msg=f"{layout}/{combine}")
         outs.append(np.asarray(got))
 for o in outs[1:]:   # all four runs agree to roundoff, not just to the ref
     np.testing.assert_allclose(o, outs[0], rtol=1e-5, atol=1e-5)
-e15 = api.get_schedule(a, b_col=8, c_col=8, mesh=mesh2d,
-                       shard_layout="1.5d", **knobs)
+e15 = api.get_schedule(a, b_col=8, c_col=8,
+                       spec=api.FusionSpec(mesh=mesh2d, shard_layout="1.5d",
+                                           **knobs))
 assert e15.shard.n_shards == 4 and e15.shard.n_repl == 2
 assert e15.shard.layout == "1.5d"
 stats = api.schedule_cache_stats()

@@ -148,12 +148,13 @@ def test_gcn_fused_equals_unfused_and_learns():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal(
         (REDUCED.n_nodes, REDUCED.in_dim)), jnp.float32)
-    y_f = model.forward(params, x, fused=True)
-    y_u = model.forward(params, x, fused=False)
+    y_f = model.forward(params, x, backend="xla")
+    y_u = model.forward(params, x, backend="unfused")
     np.testing.assert_allclose(np.asarray(y_f), np.asarray(y_u),
                                rtol=2e-3, atol=2e-3)
     labels = jnp.asarray(rng.integers(0, REDUCED.out_dim, REDUCED.n_nodes))
-    lg = jax.jit(jax.value_and_grad(lambda p: model.loss(p, x, labels)))
+    lg = jax.jit(jax.value_and_grad(
+        lambda p: model.loss(p, x, labels, backend="xla")))
     p = params
     l0, _ = lg(p)
     for _ in range(20):
@@ -172,8 +173,8 @@ def test_gcn_pallas_kernel_path():
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal(
         (REDUCED.n_nodes, REDUCED.in_dim)), jnp.float32)
-    y_pallas = model.forward(params, x, fused=True, impl="pallas")
-    y_unfused = model.forward(params, x, fused=False)
+    y_pallas = model.forward(params, x, backend="pallas")
+    y_unfused = model.forward(params, x, backend="unfused")
     np.testing.assert_allclose(np.asarray(y_pallas), np.asarray(y_unfused),
                                rtol=2e-3, atol=2e-3)
 
